@@ -42,10 +42,9 @@ class ShardingRules:
     vocab: Axis = "model"
     # logits activation vocab axis (embed/embed_act split, same reason):
     # training shards logits over "model" for memory; TP SERVING replicates
-    # them (vocab_act=None) so greedy/categorical sampling runs on a
-    # replicated operand — jax's default (non-partitionable) threefry
-    # generates DIFFERENT bits for a sharded operand, which would break
-    # sampled token-exactness vs unsharded
+    # them (vocab_act=None) and draws inside a replicated shard_map
+    # (engine._sample), so every device runs the same full-vocabulary draw
+    # as the unsharded program and sampled tokens stay bitwise equal
     vocab_act: Axis = "model"
     expert: Axis = "model"
     lora: Axis = None
@@ -233,50 +232,15 @@ def compute_context(mesh, rules: Optional[ShardingRules] = None):
     with contextlib.ExitStack() as stack:
         if rules is not None:
             stack.enter_context(rules_context(rules))
-        stack.enter_context(mesh_context(mesh))
+        stack.enter_context(jax.set_mesh(mesh))
         yield
 
 
 def current_mesh():
-    """Best-effort current-mesh lookup across jax versions.
-
-    Newer jax exposes ``jax.sharding.get_abstract_mesh``; 0.4.x tracks the
-    active mesh through ``thread_resources`` (the ``with mesh:`` context).
-    Returns None when no mesh is active (single-device tests/benches).
-    """
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is not None:
-        try:
-            m = get()
-            if hasattr(m, "empty") and not m.empty:
-                return m
-        except Exception:
-            pass
-    try:
-        from jax._src import mesh as _mesh_lib
-        get = getattr(_mesh_lib, "get_abstract_mesh", None)
-        if get is not None:
-            try:
-                m = get()
-                # an empty abstract mesh must NOT shadow an active
-                # physical `with mesh:` context — fall through
-                if hasattr(m, "empty") and not m.empty:
-                    return m
-            except Exception:
-                pass
-        pm = _mesh_lib.thread_resources.env.physical_mesh
-        return None if pm is None or pm.empty else pm
-    except Exception:
-        return None
-
-
-def mesh_context(mesh):
-    """``jax.set_mesh(mesh)`` where available, else the 0.4.x ``with mesh:``
-    context manager (both install the mesh for ``shard`` to find)."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
+    """The mesh installed by ``jax.set_mesh`` (``compute_context``), or
+    None when no mesh is active (single-device tests/benches)."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
 
 def _mesh_axis_sizes(mesh) -> dict:

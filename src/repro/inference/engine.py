@@ -52,8 +52,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ArchConfig
-from jax.experimental.shard_map import shard_map
-
 from repro.distributed.sharding import (compute_context, current_mesh,
                                         make_serving_rules, replicate_put,
                                         serving_tp_issues, shard_put_batch,
@@ -168,19 +166,18 @@ def _sample(logits, key, greedy: bool, temperature=1.0):
     if mesh is None:
         return _draw(key, lg)
     # Under a mesh the whole rng chain (split + gumbel draw) runs inside a
-    # fully-REPLICATED shard_map: each device executes the full-size,
-    # unpartitioned draw locally, bitwise identical to the unsharded
-    # program.  A with_sharding_constraint on the logits is NOT enough —
-    # it pins the consumer tensor, but GSPMD still partitions the threefry
-    # producer chain (per-device counter slices of the gumbel iota), and
-    # jax's default non-partitionable threefry pairs counter i with
-    # i + n/2 of the LOCAL slice, generating different bits than the
-    # replicated stream.  shard_map takes the chain out of GSPMD's reach.
+    # fully-REPLICATED shard_map: each device executes the full-size draw
+    # over the whole vocabulary locally, the same program as unsharded.
+    # jax's threefry is partitionable by default
+    # (jax_threefry_partitionable), so a partitioned draw would give the
+    # same bits too; the replicated draw is kept as the one path that the
+    # token-exactness pins of sharded serving were taken on, at the cost of
+    # every device drawing over the full vocabulary.
     p_rep = jax.sharding.PartitionSpec()
-    return shard_map(_draw, mesh=mesh,
-                     in_specs=(p_rep, p_rep),
-                     out_specs=(p_rep, p_rep),
-                     check_rep=False)(key, lg)
+    return jax.shard_map(_draw, mesh=mesh,
+                         in_specs=(p_rep, p_rep),
+                         out_specs=(p_rep, p_rep),
+                         check_vma=False)(key, lg)
 
 
 class Engine:
@@ -428,7 +425,7 @@ class Engine:
         key = jax.random.PRNGKey(seed)
         t0 = time.monotonic()
         # _ctx(): under a mesh the eager draw must see the mesh so _sample
-        # replicates it (sharded prefill logits → different threefry bits)
+        # runs it in its replicated shard_map
         with self._ctx():
             tok, key = _sample(logits[:, -1], key, greedy, temp)
         if lengths is None:
@@ -543,9 +540,7 @@ class Engine:
         # n_new - 1 decode steps (the scan path may execute a few more to
         # stay on a bucketed scan length; surplus tokens are truncated).
         # _ctx() so _sample finds the mesh on this EAGER call too and runs
-        # the draw in its replicated shard_map (sharded prefill logits
-        # would otherwise hand the draw a partitioned shape — different
-        # threefry bits)
+        # the draw in its replicated shard_map
         with self._ctx():
             tok, key = _sample(logits[:, -1], key, greedy, temp)
         dispatches = 0

@@ -347,6 +347,8 @@ class PagePool:
 class ContinuousEngine:
     """Resident continuous-batching engine (see module docstring)."""
 
+    _warming = False          # True inside warmup(): dispatch failures raise
+
     def __init__(self, cfg: ArchConfig, params, *,
                  config: Optional[ServingConfig] = None, **kw):
         """Build from a ``ServingConfig`` (``config=...``) or from the
@@ -505,11 +507,9 @@ class ContinuousEngine:
                 lg = jnp.where(poison[:, None],
                                jnp.full_like(lg, jnp.nan), lg)
                 finite = finite & (~active | jnp.all(jnp.isfinite(lg), -1))
-                # rows shard over "data", vocab REPLICATED per row: the
-                # per-slot draw must see its whole row locally — jax's
-                # default threefry generates different bits for a
-                # partitioned shape, so a TP mesh's idle "model" axis must
-                # not split the gumbel generation (no-op without a mesh)
+                # rows shard over "data", vocab REPLICATED per row: each
+                # per-slot draw runs over its whole row locally, as in the
+                # unsharded program (no-op without a mesh)
                 lg = shard(lg, "batch", None)
                 ks = jax.vmap(jax.random.split)(keys)         # (B, 2, 2)
                 nxt_s = jax.vmap(jax.random.categorical)(
@@ -1500,24 +1500,98 @@ class ContinuousEngine:
         1 and ``slots``), then reset.  This is the fixed chunk-shape set of
         the recompilation contract; a serving loop that skips this
         compiles lazily on first use of each bucket.  Per-request dsa_mode
-        overrides compile lazily on their first segment."""
+        overrides compile lazily on their first segment.
+
+        A segment that fails to dispatch here (it does not compile, or the
+        device runs out of memory) raises: serving would fail every request
+        the same way, so warmup surfaces the cause instead of scrubbing."""
         buckets = sorted({self.engine.prompt_bucket(int(l))
                           for l in prompt_lens})
         sink: List[RequestResult] = []
         rid = -1
-        for b in buckets:
-            prompt = np.ones((min(b, self.max_len - 2),), np.int32)
-            for n in (1, min(self.slots + 1, self.slots * 2)):
-                group = [Request(rid - j, prompt, 2) for j in range(n)]
-                for r in group:
-                    self.submit(r)
-                while self.has_work():
-                    self.admit_ready(lambda: 0.0, sink)
-                    self.step_prefill(lambda: 0.0, sink)
-                    if any(s is not None for s in self._slot):
-                        self._step_decode(lambda: 0.0, sink)
-                rid -= n
+        self._warming = True
+        try:
+            for b in buckets:
+                prompt = np.ones((min(b, self.max_len - 2),), np.int32)
+                for n in (1, min(self.slots + 1, self.slots * 2)):
+                    group = [Request(rid - j, prompt, 2) for j in range(n)]
+                    for r in group:
+                        self.submit(r)
+                    while self.has_work():
+                        self.admit_ready(lambda: 0.0, sink)
+                        self.step_prefill(lambda: 0.0, sink)
+                        if any(s is not None for s in self._slot):
+                            self._step_decode(lambda: 0.0, sink)
+                    rid -= n
+        finally:
+            self._warming = False
         self.reset()
+
+    # -- serving-path probes ---------------------------------------------------
+
+    def first_step_logits(self, prompts: Sequence[np.ndarray],
+                          first_tokens: Optional[np.ndarray] = None,
+                          bucket: Optional[int] = None
+                          ) -> Tuple[np.ndarray, np.ndarray]:
+        """Logits of the serving path for up to ``slots`` prompts, for
+        checking one DSA path against another: the prompts stream through a
+        staging cache of ``bucket`` rows (default: the longest prompt's
+        bucket) with the chunk program chunked admission runs, then one
+        decode step runs on that cache from ``first_tokens`` (default: the
+        greedy first tokens; pass another path's so both steps see the same
+        input).  Returns (prompt_logits, step_logits), each (len(prompts),
+        vocab) float32.  The resident serving state is not touched."""
+        n = len(prompts)
+        assert 0 < n <= self.slots, (n, self.slots)
+        longest = max(len(p) for p in prompts)
+        bucket = bucket or self.engine.prompt_bucket(longest)
+        # the decode step writes row len(prompt): it must be in the bucket
+        assert longest < bucket, (longest, bucket)
+        c = min(self.chunk_tokens, pow2_bucket(bucket, self._chunk_floor))
+        bpf = 1 if n == 1 else self.slots
+        mat = np.full((bpf, -(-longest // c) * c), self.engine.pad_id,
+                      np.int32)
+        lengths = np.empty((bpf,), np.int32)
+        for j in range(bpf):
+            p = np.asarray(prompts[min(j, n - 1)], np.int32)
+            mat[j, :len(p)] = p
+            lengths[j] = len(p)
+        caches = self._put_cache(unstack_group_caches(
+            init_cache(self.cfg, bpf, bucket, self.engine.decode_flags,
+                       dtype=self.engine.cache_dtype)))
+        flags = self._flags(self.engine.decode_flags.dsa_mode)
+        active = self._put_b(np.ones((bpf,), bool))
+        prompt_logits = np.zeros((bpf, self.cfg.vocab), np.float32)
+        with self._ctx():
+            for j in range(mat.shape[1] // c):
+                cl = np.clip(lengths - j * c, 0, c).astype(np.int32)
+                last, caches = self._chunk(
+                    self.engine.params, caches,
+                    self._put_b(mat[:, j * c:(j + 1) * c]), self._put_b(cl),
+                    active, flags=flags, sel_len=bucket)
+                done = (lengths > j * c) & (lengths <= (j + 1) * c)
+                prompt_logits[done] = np.asarray(last, np.float32)[done]
+            tok = prompt_logits.argmax(-1).astype(np.int32)
+            if first_tokens is not None:
+                tok[:n] = first_tokens
+            tok = tok[:, None]
+            step, _ = self.engine._decode(self.engine.params,
+                                          self._put_b(tok), caches,
+                                          flags=flags)
+        return prompt_logits[:n], np.asarray(step[:, -1], np.float32)[:n]
+
+    def segment_hlo(self) -> str:
+        """Compiled HLO text of the decode segment on the current resident
+        state — shows which kernels serving runs."""
+        with self._ctx():
+            lowered = self._segment.lower(
+                self.engine.params, self._put_b(self._tok), self._caches,
+                self._put_b(self._keys), self._put_b(self._active),
+                self._put_b(self._greedy), self._put_b(self._temps),
+                self._put_b(np.zeros((self.slots,), np.int32)),
+                self._put_b(np.zeros((self.slots,), bool)),
+                flags=self._flags(self.engine.decode_flags.dsa_mode))
+        return lowered.compile().as_text()
 
     # -- decode segments ----------------------------------------------------
 
@@ -1560,6 +1634,8 @@ class ContinuousEngine:
             fin = np.asarray(fin)
             toks = np.asarray(toks)                   # (slots, seg_len)
         except Exception as e:              # noqa: BLE001 — fail partially
+            if self._warming:
+                raise
             # the dispatched computation itself failed: the DONATED caches
             # can no longer be trusted — fail the in-flight batch, rebuild,
             # keep serving the queue

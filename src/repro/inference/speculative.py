@@ -203,10 +203,8 @@ def _make_verify(cfg: ArchConfig):
         nxt_g = jnp.argmax(logits, -1).astype(jnp.int32)     # (B, C)
 
         def chain(ks_carry, lg_i):
-            # rows shard over "data", vocab replicated per row: a TP
-            # mesh's idle "model" axis must not split the gumbel bit
-            # generation (non-partitionable threefry — see the scheduler's
-            # segment sampling); no-op without a mesh
+            # rows shard over "data", vocab replicated per row, as in the
+            # scheduler's segment sampling; no-op without a mesh
             lg_i = shard(lg_i, "batch", None)
             kk = jax.vmap(jax.random.split)(ks_carry)        # (B, 2, 2)
             smp = jax.vmap(jax.random.categorical)(

@@ -9,24 +9,27 @@ per-row cache lengths all arrive through scalar prefetch
 scales with the number of selected blocks — the paper's decode-time FLOP
 saving made visible to the memory system.
 
-Layouts (kernel-native; repro.kernels.ops.dsa_decode adapts model layout):
+Layouts (the engine's own — no transposes at the call site):
 
-  q:       (B, Hq, 1, hd)     current query token, per head
-  k/v:     (B, S, Hkv, hd)    KV cache in its natural engine layout
-                              (S padded to a multiple of block_k)
+  q:       (B, 1, Hq, hd)     current query token, all heads
+  k/v:     (B, S, Hkv, hd)    KV cache (S padded to a multiple of block_k)
   idx/ok:  (B, nb) int32      selected cache-block indices + validity
   kv_len:  (B,) int32         valid cache rows — ragged per row: batches mix
                               prompt lengths, and under continuous batching
                               every resident slot decodes at its own cache
                               depth (retired/unadmitted slots pass 0 and
                               contribute no valid attention support)
-  out:     (B, Hq, 1, hd)
+  out:     (B, 1, Hq, hd)
 
-Grid: (B, Hq, nb); the innermost axis accumulates online softmax and
-finalizes on the last selected block.  GQA: query head h reads KV head
-h // (Hq // Hkv) straight from the cache — no head repetition is ever
-materialized.  Selected indices are pre-sorted ascending by the mask
-builder (contiguous HBM streams, paper §5.2 reordering analogue).
+Grid: (B, nb); the innermost axis accumulates online softmax and finalizes
+on the last selected block.  Each step streams ONE row-block of the cache
+with every KV head, ``(1, block_k, Hkv, hd)``, so a selected block is DMA'd
+once however many query heads read it; the head loop runs inside the
+kernel.  The block's two minor dims are the cache's own (Hkv, hd), which is
+what Mosaic's TPU tiling asks of a block.  GQA: query heads
+[h*g, (h+1)*g) read KV head h — no head repetition is ever materialized.
+Selected indices are pre-sorted ascending by the mask builder (contiguous
+HBM streams, paper §5.2 reordering analogue).
 """
 from __future__ import annotations
 
@@ -40,10 +43,19 @@ from jax.experimental.pallas import tpu as pltpu
 NEG = -1e30
 
 
-def _kernel(idx_ref, ok_ref, kvl_ref, q_ref, k_ref, v_ref, o_ref,
-            acc_ref, m_ref, l_ref, *, block_k: int, nb: int, scale: float,
-            ks_ref=None, vs_ref=None):
-    b, j = pl.program_id(0), pl.program_id(2)
+def _kernel(*refs, block_k: int, nb: int, n_kv: int, scale: float,
+            quant: bool, n_prefetch: int):
+    # a paged call prefetches a 4th stream, the physical pages: it steers
+    # the index maps only — the body masks from idx, the LOGICAL blocks,
+    # which carry the key positions
+    idx_ref, ok_ref, kvl_ref = refs[:3]
+    refs = refs[n_prefetch:]
+    if quant:
+        q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = refs
+    else:
+        q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs
+    b, j = pl.program_id(0), pl.program_id(1)
+    g = q_ref.shape[2] // n_kv
 
     @pl.when(j == 0)
     def _init():
@@ -53,64 +65,87 @@ def _kernel(idx_ref, ok_ref, kvl_ref, q_ref, k_ref, v_ref, o_ref,
 
     kb = idx_ref[b, j]
     ok = ok_ref[b, j]
-    kvl = kvl_ref[b]
+    kpos = kb * block_k + jax.lax.broadcasted_iota(jnp.int32, (g, block_k), 1)
+    mask = (kpos < kvl_ref[b]) & (ok > 0)
+    # one load of the whole query block; each KV head slices its g rows
+    q_all = q_ref[0, 0].astype(jnp.float32) * scale        # (Hq, hd)
 
-    q = q_ref[0, 0].astype(jnp.float32) * scale            # (1, hd)
-    k = k_ref[0, :, 0].astype(jnp.float32)                 # (Bk, hd)
-    if ks_ref is not None:
-        # dequant-on-gather: int8/fp8 cache rows land in VMEM narrow and
-        # return to f32 against their per-row scales only once streamed
-        k = k * ks_ref[0, :, 0][:, None]
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)  # (1, Bk)
-    kpos = kb * block_k + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
-    mask = (kpos < kvl) & (ok > 0)
-    s = jnp.where(mask, s, NEG)
-
-    m_prev = m_ref[...]                                    # (1, 1)
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    # explicit zero under the mask: a fully-invalid block would otherwise
-    # contribute exp(NEG - NEG) = 1 while m is still at its NEG init
-    p = jnp.where(mask, jnp.exp(s - m_new), 0.0)           # (1, Bk)
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-    v = v_ref[0, :, 0].astype(jnp.float32)                 # (Bk, hd)
-    if vs_ref is not None:
-        v = v * vs_ref[0, :, 0][:, None]
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
+    for h in range(n_kv):
+        rows = slice(h * g, (h + 1) * g)
+        q = q_all[rows]
+        k = k_ref[0, :, h, :].astype(jnp.float32)          # (Bk, hd)
+        v = v_ref[0, :, h, :].astype(jnp.float32)
+        if quant:
+            # dequant-on-gather: int8/fp8 cache rows land in VMEM narrow
+            # and return to f32 against their per-row scales only here
+            k = k * ks_ref[0, :, h:h + 1]
+            v = v * vs_ref[0, :, h:h + 1]
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)  # (g, Bk)
+        s = jnp.where(mask, s, NEG)
+        m_prev = m_ref[rows]                               # (g, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        # explicit zero under the mask: a fully-invalid block would
+        # otherwise contribute exp(NEG - NEG) = 1 while m is still at NEG
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)       # (g, Bk)
+        l_ref[rows] = l_ref[rows] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[rows] = acc_ref[rows] * alpha + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        m_ref[rows] = m_new
 
     @pl.when(j == nb - 1)
     def _fini():
-        denom = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / denom).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+                       ).astype(o_ref.dtype)
 
 
-def _quant_kernel(idx_ref, ok_ref, kvl_ref, q_ref, k_ref, v_ref, ks_ref,
-                  vs_ref, o_ref, acc_ref, m_ref, l_ref, *, block_k: int,
-                  nb: int, scale: float):
-    _kernel(idx_ref, ok_ref, kvl_ref, q_ref, k_ref, v_ref, o_ref,
-            acc_ref, m_ref, l_ref, block_k=block_k, nb=nb, scale=scale,
-            ks_ref=ks_ref, vs_ref=vs_ref)
+def _call(q, k, v, k_scale, v_scale, prefetch, block_row, *, block_k,
+          interpret):
+    """One pallas_call for the dense and paged variants.  ``prefetch`` is
+    (idx, ok, kv_len[, pidx]); ``block_row(b, j, *prefetch_refs)`` gives the
+    (batch, block) coordinates of the cache row-block to stream."""
+    b, _, hq, hd = q.shape
+    hkv = k.shape[2]
+    nb = prefetch[0].shape[-1]
+    quant = k_scale is not None
 
+    def qmap(bi, ji, *refs):
+        return (bi, 0, 0, 0)
 
-def _paged_kernel(idx_ref, ok_ref, kvl_ref, pidx_ref, q_ref, k_ref, v_ref,
-                  o_ref, acc_ref, m_ref, l_ref, *, block_k: int, nb: int,
-                  scale: float):
-    # pidx_ref steers the BlockSpec index maps (which PHYSICAL page to
-    # stream); the body is the dense kernel's — it masks from idx_ref,
-    # the LOGICAL block stream, which carries the key positions
-    _kernel(idx_ref, ok_ref, kvl_ref, q_ref, k_ref, v_ref, o_ref,
-            acc_ref, m_ref, l_ref, block_k=block_k, nb=nb, scale=scale)
+    def kmap(bi, ji, *refs):
+        return block_row(bi, ji, *refs) + (0, 0)
 
+    def smap(bi, ji, *refs):
+        return block_row(bi, ji, *refs) + (0,)
 
-def _paged_quant_kernel(idx_ref, ok_ref, kvl_ref, pidx_ref, q_ref, k_ref,
-                        v_ref, ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref,
-                        *, block_k: int, nb: int, scale: float):
-    _kernel(idx_ref, ok_ref, kvl_ref, q_ref, k_ref, v_ref, o_ref,
-            acc_ref, m_ref, l_ref, block_k=block_k, nb=nb, scale=scale,
-            ks_ref=ks_ref, vs_ref=vs_ref)
+    in_specs = [pl.BlockSpec((1, 1, hq, hd), qmap),
+                pl.BlockSpec((1, block_k, hkv, hd), kmap),
+                pl.BlockSpec((1, block_k, hkv, hd), kmap)]
+    args = [q, k, v]
+    if quant:
+        in_specs += [pl.BlockSpec((1, block_k, hkv), smap)] * 2
+        args += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
+    kern = functools.partial(_kernel, block_k=block_k, nb=nb, n_kv=hkv,
+                             scale=hd ** -0.5, quant=quant,
+                             n_prefetch=len(prefetch))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch),
+        grid=(b, nb),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, 1, hq, hd), qmap),
+        scratch_shapes=[
+            pltpu.VMEM((hq, hd), jnp.float32),
+            pltpu.VMEM((hq, 1), jnp.float32),
+            pltpu.VMEM((hq, 1), jnp.float32),
+        ],
+    )
+    fn = pl.pallas_call(
+        kern, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, 1, hq, hd), q.dtype),
+        interpret=interpret,
+    )
+    return fn(*(p.astype(jnp.int32) for p in prefetch), *args)
 
 
 def dsa_decode_paged_gather_attention(q, k_pool, v_pool, idx, pidx, ok,
@@ -125,123 +160,39 @@ def dsa_decode_paged_gather_attention(q, k_pool, v_pool, idx, pidx, ok,
     the slot's page table (HBM->VMEM gather steering).  k_scale/v_scale:
     optional (P*block_k, Hkv) per-row scales of an int8/fp8 pool, streamed
     through the same physical-page index maps (dequant-on-gather).
-    Returns (B,Hq,1,hd)."""
-    b, hq, _, hd = q.shape
-    hkv = k_pool.shape[1]
-    g = hq // hkv
-    nb = idx.shape[-1]
-    scale = hd ** -0.5
+    q: (B,1,Hq,hd).  Returns (B,1,Hq,hd)."""
     # pool rows are page-aligned by construction — no tail padding
     assert k_pool.shape[0] % block_k == 0, (k_pool.shape, block_k)
-    kp = k_pool[None]                                      # (1, P*Bk, Hkv, hd)
-    vp = v_pool[None]
-    grid = (b, hq, nb)
 
-    def qmap(bi, hi, ji, idx_ref, ok_ref, kvl_ref, pidx_ref):
-        return (bi, hi, 0, 0)
-
-    def kmap(bi, hi, ji, idx_ref, ok_ref, kvl_ref, pidx_ref):
-        return (0, pidx_ref[bi, ji], hi // g, 0)
-
-    def smap(bi, hi, ji, idx_ref, ok_ref, kvl_ref, pidx_ref):
-        return (0, pidx_ref[bi, ji], hi // g)
+    def block_row(bi, ji, idx_ref, ok_ref, kvl_ref, pidx_ref):
+        return (0, pidx_ref[bi, ji])
 
     quant = k_scale is not None
-    kern = functools.partial(
-        _paged_quant_kernel if quant else _paged_kernel,
-        block_k=block_k, nb=nb, scale=scale)
-    in_specs = [
-        pl.BlockSpec((1, 1, 1, hd), qmap),
-        pl.BlockSpec((1, block_k, 1, hd), kmap),
-        pl.BlockSpec((1, block_k, 1, hd), kmap),
-    ]
-    if quant:
-        in_specs += [pl.BlockSpec((1, block_k, 1), smap),
-                     pl.BlockSpec((1, block_k, 1), smap)]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, 1, hd), qmap),
-        scratch_shapes=[
-            pltpu.VMEM((1, hd), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-        ],
-    )
-    fn = pl.pallas_call(
-        kern, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hq, 1, hd), q.dtype),
-        interpret=interpret,
-    )
-    args = (idx.astype(jnp.int32), ok.astype(jnp.int32),
-            kv_len.astype(jnp.int32), pidx.astype(jnp.int32), q, kp, vp)
-    if quant:
-        args += (k_scale.astype(jnp.float32)[None],
-                 v_scale.astype(jnp.float32)[None])
-    return fn(*args)
+    return _call(q, k_pool[None], v_pool[None],
+                 k_scale[None] if quant else None,
+                 v_scale[None] if quant else None,
+                 (idx, ok, kv_len, pidx), block_row, block_k=block_k,
+                 interpret=interpret)
 
 
 def dsa_decode_gather_attention(q, k_cache, v_cache, idx, ok, kv_len, *,
                                 block_k: int = 128,
                                 k_scale=None, v_scale=None,
                                 interpret: bool = False) -> jax.Array:
-    """q: (B,Hq,1,hd); k/v cache: (B,S,Hkv,hd); idx/ok: (B,nb);
+    """q: (B,1,Hq,hd); k/v cache: (B,S,Hkv,hd); idx/ok: (B,nb);
     kv_len: (B,).  k_scale/v_scale: optional (B,S,Hkv) per-row scales of
-    an int8/fp8 cache (dequant-on-gather).  Returns (B,Hq,1,hd)."""
-    b, hq, _, hd = q.shape
-    s_len, hkv = k_cache.shape[1], k_cache.shape[2]
-    g = hq // hkv
-    nb = idx.shape[-1]
-    scale = hd ** -0.5
-    n_kb = -(-s_len // block_k)
-    pad = n_kb * block_k - s_len
+    an int8/fp8 cache (dequant-on-gather).  Returns (B,1,Hq,hd)."""
+    s_len = k_cache.shape[1]
+    pad = -(-s_len // block_k) * block_k - s_len
     if pad:
         k_cache = jnp.pad(k_cache, ((0, 0), (0, pad), (0, 0), (0, 0)))
         v_cache = jnp.pad(v_cache, ((0, 0), (0, pad), (0, 0), (0, 0)))
         if k_scale is not None:
             k_scale = jnp.pad(k_scale, ((0, 0), (0, pad), (0, 0)))
             v_scale = jnp.pad(v_scale, ((0, 0), (0, pad), (0, 0)))
-    grid = (b, hq, nb)
 
-    def qmap(bi, hi, ji, idx_ref, ok_ref, kvl_ref):
-        return (bi, hi, 0, 0)
+    def block_row(bi, ji, idx_ref, ok_ref, kvl_ref):
+        return (bi, idx_ref[bi, ji])
 
-    def kmap(bi, hi, ji, idx_ref, ok_ref, kvl_ref):
-        return (bi, idx_ref[bi, ji], hi // g, 0)
-
-    def smap(bi, hi, ji, idx_ref, ok_ref, kvl_ref):
-        return (bi, idx_ref[bi, ji], hi // g)
-
-    quant = k_scale is not None
-    kern = functools.partial(_quant_kernel if quant else _kernel,
-                             block_k=block_k, nb=nb, scale=scale)
-    in_specs = [
-        pl.BlockSpec((1, 1, 1, hd), qmap),
-        pl.BlockSpec((1, block_k, 1, hd), kmap),
-        pl.BlockSpec((1, block_k, 1, hd), kmap),
-    ]
-    if quant:
-        in_specs += [pl.BlockSpec((1, block_k, 1), smap),
-                     pl.BlockSpec((1, block_k, 1), smap)]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, 1, hd), qmap),
-        scratch_shapes=[
-            pltpu.VMEM((1, hd), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-        ],
-    )
-    fn = pl.pallas_call(
-        kern, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hq, 1, hd), q.dtype),
-        interpret=interpret,
-    )
-    args = (idx.astype(jnp.int32), ok.astype(jnp.int32),
-            kv_len.astype(jnp.int32), q, k_cache, v_cache)
-    if quant:
-        args += (k_scale.astype(jnp.float32), v_scale.astype(jnp.float32))
-    return fn(*args)
+    return _call(q, k_cache, v_cache, k_scale, v_scale, (idx, ok, kv_len),
+                 block_row, block_k=block_k, interpret=interpret)

@@ -1,11 +1,17 @@
 """jit'd public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True off-TPU so the same call sites work in CPU
-tests and on real hardware.  Setting the environment variable
-``JAX_PALLAS_INTERPRET=1`` forces interpret mode regardless of backend —
-CI uses it in a dedicated job so kernel-vs-XLA-twin equivalence is
-exercised explicitly on CPU runners rather than relying on the backend
-default.  Model code calls these through RunFlags(dsa_mode="kernel").
+The kernels compile for the TPU.  ``interpret`` defaults to True on the
+CPU backend only, so the same call sites run in CPU tests through the
+Pallas interpreter; any other backend that is not a TPU is an error rather
+than a silent detour through the interpreter.  Setting the environment
+variable ``JAX_PALLAS_INTERPRET=1`` forces interpret mode — CI uses it in a
+dedicated job so kernel-vs-XLA-twin equivalence is exercised explicitly.
+Model code calls these through RunFlags(dsa_mode="kernel").
+
+GSPMD cannot partition a Mosaic kernel, so under a serving mesh each
+attention kernel runs per shard (``_per_shard``): it reads whole slot rows
+and whole heads, and runs unchanged on the slots ("data") and heads
+("model") its shard holds.
 """
 from __future__ import annotations
 
@@ -13,7 +19,9 @@ import functools
 import os
 
 import jax
+from jax.sharding import PartitionSpec as P
 
+from repro.distributed.sharding import current_mesh, resolve_spec
 from repro.kernels.dsa_attention import dsa_block_sparse_attention
 from repro.kernels.dsa_chunk_prefill import (dsa_chunk_gather_attention,
                                              dsa_chunk_paged_gather_attention)
@@ -25,7 +33,45 @@ from repro.kernels.wkv6 import wkv6_chunked
 def _default_interpret() -> bool:
     if os.environ.get("JAX_PALLAS_INTERPRET", "").lower() in ("1", "true"):
         return True
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"Pallas kernels target the TPU; backend {backend!r} has no "
+            "lowering (set JAX_PALLAS_INTERPRET=1 to run them interpreted)")
+    return backend == "cpu"
+
+
+def _per_shard(call, operands, n_heads):
+    """``call(*arrays)`` on each shard of the active mesh (a plain call
+    without one).  ``operands``: (array or None, axes) pairs, where axes
+    name each dim "B" (slots: the mesh's batch axis), "H" (heads: the
+    kv-head axis, resolved on ``n_heads`` KV heads so query and KV heads
+    always split alike) or None (whole on every shard).  The output is
+    (B, L, Hq, hd)."""
+    arrays = [a for a, _ in operands if a is not None]
+    mesh = current_mesh()
+    if mesh is None:
+        return call(*arrays)
+
+    def ax(size, name):
+        return (tuple(resolve_spec((size,), (name,), mesh=mesh)) + (None,))[0]
+
+    b_ax = ax(arrays[0].shape[0], "batch")
+    h_ax = ax(n_heads, "kv_heads")
+    pick = {"B": b_ax, "H": h_ax, None: None}
+    specs = tuple(P(*(pick[n] for n in axes))
+                  for a, axes in operands if a is not None)
+    return jax.shard_map(call, mesh=mesh, in_specs=specs,
+                         out_specs=P(b_ax, None, h_ax, None),
+                         check_vma=False)(*arrays)
+
+
+def _scales(sc):
+    return dict(zip(("k_scale", "v_scale"), sc))
+
+
+_ROWS = ("B", None, "H", None)          # (B, L, H, hd) q / cache / output
+_POOL = (None, "H", None)               # (P*block_k, Hkv, hd) page pool
 
 
 @functools.partial(jax.jit, static_argnames=("block_q", "block_k", "causal",
@@ -35,14 +81,17 @@ def dsa_attention(q, k, v, idx, valid, *, block_q=128, block_k=128,
     """q: (B,Lq,Hq,hd) [model layout]; k/v: (B,Lk,Hkv,hd);
     idx/valid: (B,nQb,nb).  Returns (B,Lq,Hq,hd)."""
     interpret = _default_interpret() if interpret is None else interpret
-    qt = q.transpose(0, 2, 1, 3)
-    kt = k.transpose(0, 2, 1, 3)
-    vt = v.transpose(0, 2, 1, 3)
-    out = dsa_block_sparse_attention(qt, kt, vt, idx, valid,
-                                     block_q=block_q, block_k=block_k,
-                                     causal=causal, window=window,
-                                     interpret=interpret)
-    return out.transpose(0, 2, 1, 3)
+
+    def call(q, k, v, idx, valid):
+        out = dsa_block_sparse_attention(
+            q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+            v.transpose(0, 2, 1, 3), idx, valid, block_q=block_q,
+            block_k=block_k, causal=causal, window=window,
+            interpret=interpret)
+        return out.transpose(0, 2, 1, 3)
+    return _per_shard(call, [(q, _ROWS), (k, _ROWS), (v, _ROWS),
+                             (idx, ("B", None, None)),
+                             (valid, ("B", None, None))], k.shape[2])
 
 
 @functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
@@ -57,11 +106,15 @@ def dsa_decode(q, k_cache, v_cache, idx, ok, kv_len, *, block_k=128,
     The pure-XLA twin is core.attention.dsa_decode_block_attention.
     """
     interpret = _default_interpret() if interpret is None else interpret
-    qt = q.transpose(0, 2, 1, 3)                    # (B,Hq,1,hd)
-    out = dsa_decode_gather_attention(qt, k_cache, v_cache, idx, ok, kv_len,
-                                      block_k=block_k, k_scale=k_scale,
-                                      v_scale=v_scale, interpret=interpret)
-    return out.transpose(0, 2, 1, 3)
+
+    def call(q, k, v, idx, ok, kv_len, *sc):
+        return dsa_decode_gather_attention(q, k, v, idx, ok, kv_len,
+                                           block_k=block_k,
+                                           interpret=interpret, **_scales(sc))
+    return _per_shard(call, [(q, _ROWS), (k_cache, _ROWS), (v_cache, _ROWS),
+                             (idx, ("B", None)), (ok, ("B", None)),
+                             (kv_len, ("B",)), (k_scale, ("B", None, "H")),
+                             (v_scale, ("B", None, "H"))], k_cache.shape[2])
 
 
 @functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
@@ -77,12 +130,16 @@ def dsa_decode_paged(q, k_pool, v_pool, idx, pidx, ok, kv_len, *,
     The pure-XLA twin is core.attention.dsa_decode_paged_block_attention.
     """
     interpret = _default_interpret() if interpret is None else interpret
-    qt = q.transpose(0, 2, 1, 3)                    # (B,Hq,1,hd)
-    out = dsa_decode_paged_gather_attention(qt, k_pool, v_pool, idx, pidx,
-                                            ok, kv_len, block_k=block_k,
-                                            k_scale=k_scale, v_scale=v_scale,
-                                            interpret=interpret)
-    return out.transpose(0, 2, 1, 3)
+
+    def call(q, k, v, idx, pidx, ok, kv_len, *sc):
+        return dsa_decode_paged_gather_attention(
+            q, k, v, idx, pidx, ok, kv_len, block_k=block_k,
+            interpret=interpret, **_scales(sc))
+    return _per_shard(call, [(q, _ROWS), (k_pool, _POOL), (v_pool, _POOL),
+                             (idx, ("B", None)), (pidx, ("B", None)),
+                             (ok, ("B", None)), (kv_len, ("B",)),
+                             (k_scale, (None, "H")), (v_scale, (None, "H"))],
+                      k_pool.shape[1])
 
 
 @functools.partial(jax.jit, static_argnames=("block_q", "block_k",
@@ -100,12 +157,16 @@ def dsa_chunk_prefill(q, k_cache, v_cache, idx, ok, q_off, kv_len, *,
     core.attention.dsa_chunk_block_attention.
     """
     interpret = _default_interpret() if interpret is None else interpret
-    qt = q.transpose(0, 2, 1, 3)                    # (B,Hq,C,hd)
-    out = dsa_chunk_gather_attention(qt, k_cache, v_cache, idx, ok, q_off,
-                                     kv_len, block_q=block_q,
-                                     block_k=block_k, k_scale=k_scale,
-                                     v_scale=v_scale, interpret=interpret)
-    return out.transpose(0, 2, 1, 3)
+
+    def call(q, k, v, idx, ok, q_off, kv_len, *sc):
+        return dsa_chunk_gather_attention(
+            q, k, v, idx, ok, q_off, kv_len, block_q=block_q,
+            block_k=block_k, interpret=interpret, **_scales(sc))
+    return _per_shard(call, [(q, _ROWS), (k_cache, _ROWS), (v_cache, _ROWS),
+                             (idx, ("B", None, None)),
+                             (ok, ("B", None, None)), (q_off, ("B",)),
+                             (kv_len, ("B",)), (k_scale, ("B", None, "H")),
+                             (v_scale, ("B", None, "H"))], k_cache.shape[2])
 
 
 @functools.partial(jax.jit, static_argnames=("block_q", "block_k",
@@ -121,13 +182,17 @@ def dsa_chunk_prefill_paged(q, k_pool, v_pool, idx, pidx, ok, q_off,
     optional (P*block_k,Hkv) per-row pool scales.  Returns (B,C,Hq,hd).
     """
     interpret = _default_interpret() if interpret is None else interpret
-    qt = q.transpose(0, 2, 1, 3)                    # (B,Hq,C,hd)
-    out = dsa_chunk_paged_gather_attention(qt, k_pool, v_pool, idx, pidx,
-                                           ok, q_off, kv_len,
-                                           block_q=block_q, block_k=block_k,
-                                           k_scale=k_scale, v_scale=v_scale,
-                                           interpret=interpret)
-    return out.transpose(0, 2, 1, 3)
+
+    def call(q, k, v, idx, pidx, ok, q_off, kv_len, *sc):
+        return dsa_chunk_paged_gather_attention(
+            q, k, v, idx, pidx, ok, q_off, kv_len, block_q=block_q,
+            block_k=block_k, interpret=interpret, **_scales(sc))
+    return _per_shard(call, [(q, _ROWS), (k_pool, _POOL), (v_pool, _POOL),
+                             (idx, ("B", None, None)),
+                             (pidx, ("B", None, None)),
+                             (ok, ("B", None, None)), (q_off, ("B",)),
+                             (kv_len, ("B",)), (k_scale, (None, "H")),
+                             (v_scale, (None, "H"))], k_pool.shape[1])
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))

@@ -31,8 +31,8 @@ import jax.numpy as jnp
 
 from repro.configs.base import (ARCH_IDS, LONG_CTX_ARCHS, SHAPES, get_config)
 from repro.distributed import hlo_analysis as H
-from repro.distributed.sharding import (make_rules, mesh_context,
-                                        resolve_spec, set_rules, tree_specs)
+from repro.distributed.sharding import (make_rules, resolve_spec, set_rules,
+                                        tree_specs)
 from repro.launch import specs as SP
 from repro.launch.mesh import make_production_mesh
 from repro.models import blocks as B
@@ -174,7 +174,7 @@ def _component_costs(cfg, shape, rules, mesh, flags, mb, opt=None):
     kind = shape.kind
     ng = B.n_groups(cfg)
     n_enc = cfg.n_enc_layers if cfg.enc_dec else 0
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         if kind == "train":
             gb = shape.global_batch // mb
             d1, d2 = _depth_cfg(cfg, 1, 1), _depth_cfg(cfg, 2, 1)
@@ -234,7 +234,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
     opt = adamw.OptConfig(
         moment_dtype="bfloat16" if cfg.num_params() > 5e10 else "float32")
     t0 = time.time()
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             flags = RunFlags(mode="train", dsa_mode=dsa_mode)
             state_structs, state_log = SP.train_state_structs(cfg, opt)

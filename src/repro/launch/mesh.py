@@ -40,15 +40,15 @@ def make_serving_mesh(dp: int = 0, tp: int = 1, cfg=None):
     head/mlp/expert axis raises a ``ValueError`` naming the offending
     axis here instead of surfacing as a deep XLA sharding error (the
     engines themselves fall back to replicated weights gracefully when
-    handed an indivisible mesh without this validation)."""
+    handed an indivisible mesh without this validation).
+
+    The axes are ``Auto``: GSPMD places the activations from the
+    constraints in the model (``jax.make_mesh`` would default to
+    ``Explicit`` axes, which ask every gather for an output sharding)."""
     tp = max(1, int(tp))
     if tp == 1:
-        n = dp or len(jax.devices())
-        try:
-            return jax.make_mesh((n,), ("data",))
-        except Exception:       # older jax without jax.make_mesh
-            import numpy as np
-            return jax.sharding.Mesh(np.asarray(jax.devices()[:n]), ("data",))
+        return jax.make_mesh((dp or len(jax.devices()),), ("data",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
     if cfg is not None:
         from repro.distributed.sharding import serving_tp_issues
         issues = serving_tp_issues(cfg, tp)
@@ -66,12 +66,8 @@ def make_serving_mesh(dp: int = 0, tp: int = 1, cfg=None):
     if dp * tp > n:
         raise ValueError(f"dp={dp} x tp={tp} needs {dp * tp} devices, "
                          f"only {n} visible")
-    try:
-        return jax.make_mesh((dp, tp), ("data", "model"))
-    except Exception:           # older jax without jax.make_mesh
-        import numpy as np
-        devs = np.asarray(jax.devices()[:dp * tp]).reshape(dp, tp)
-        return jax.sharding.Mesh(devs, ("data", "model"))
+    return jax.make_mesh((dp, tp), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def init_serving_processes(coordinator: str, num_processes: int,

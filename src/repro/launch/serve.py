@@ -7,7 +7,9 @@
 ``--loop scan`` (default) is the decode fast path: all new tokens are
 generated in one fused on-device ``lax.scan`` dispatch.  ``--dsa-mode
 kernel`` additionally routes each decode step through the fused Pallas
-gather kernel (interpret mode off-TPU).
+gather kernel (interpreted on the CPU backend).  The persistent compile
+cache lives where ``JAX_COMPILATION_CACHE_DIR`` says, else in
+``<repo>/.jax_cache``.
 
 ``--continuous`` switches from one static batch to the continuous-batching
 serving loop (repro.inference.scheduler): a synthetic open-loop Poisson
@@ -44,8 +46,11 @@ block-selection keep-rates.
 from __future__ import annotations
 
 import argparse
+import os
+import pathlib
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import get_config, reduced
@@ -59,12 +64,27 @@ from repro.launch.mesh import init_serving_processes, make_serving_mesh
 from repro.models.transformer import init_model
 
 
+REPO_ROOT = pathlib.Path(__file__).resolve().parents[3]
+
+
+def use_compile_cache() -> str:
+    """Keep JAX's persistent compile cache where ``JAX_COMPILATION_CACHE_DIR``
+    says (JAX reads it itself), else at the fixed ``<repo>/.jax_cache`` —
+    the path is part of the cache key, so it never moves.  Call before the
+    first compile; returns the directory in use."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir",
+                          str(REPO_ROOT / ".jax_cache"))
+    return jax.config.jax_compilation_cache_dir
+
+
 def _serving_config(cfg, args, max_len, dsa_on, mesh,
                     telemetry=None) -> ServingConfig:
     """One ServingConfig for both engines, straight from the CLI flags."""
     return ServingConfig(
         max_len=max_len, long_context=dsa_on,
         dsa_mode=args.dsa_mode if dsa_on else "off",
+        cache_dtype=jnp.dtype(cfg.dtype),
         moe_prefill=args.moe_prefill, mesh=mesh, loop=args.loop,
         select_dtype=args.select_dtype if dsa_on else "float32",
         kv_quant=args.kv_quant,
@@ -125,6 +145,9 @@ def _serve_continuous(cfg, args, params, config):
         if isinstance(kr, tuple) and kr[0]:   # plain float 0.0 = no probe
             print(f"sparsity  : {kr[0]} DSA selection samples, "
                   f"mean keep-rate {kr[1]:.2f}")
+    if s["n_failed"]:
+        raise SystemExit(f"{s['n_failed']} request(s) failed; last error: "
+                         f"{eng.health()['last_error']}")
     return results
 
 
@@ -228,6 +251,7 @@ def main(argv=None):
                          "--trace-out/--metrics-out; default 0 = off)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     # multi-controller: every process enumerates the GLOBAL device set
     # after this, so it must run before any jax device use below

@@ -20,7 +20,7 @@ from repro.checkpoint.checkpoint import AsyncCheckpointer
 from repro.configs.base import get_config, reduced
 from repro.data.synthetic import DataConfig, make_batches
 from repro.distributed.fault_tolerance import StepWatchdog, elastic_mesh
-from repro.distributed.sharding import (make_rules, mesh_context, set_rules,
+from repro.distributed.sharding import (make_rules, set_rules,
                                         tree_specs)
 from repro.launch.mesh import make_production_mesh
 from repro.models.attention import RunFlags
@@ -71,7 +71,7 @@ def main(argv=None):
                       global_batch=args.batch, seed=args.seed)
     data = make_batches(args.data, dcfg)
 
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         state, state_log = ST.init_train_state(
             jax.random.PRNGKey(args.seed), cfg, opt)
         state_specs = tree_specs(state, state_log, rules, mesh)

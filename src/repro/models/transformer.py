@@ -331,8 +331,7 @@ def forward(params, cfg: ArchConfig, flags: RunFlags,
     logits = x @ head
     # "vocab_act", not "vocab": training shards logits over "model", but
     # the TP serving rules replicate them here (all-gather of columns each
-    # computed whole) so sampling sees a replicated operand — identical
-    # threefry bits, token-exact vs unsharded
+    # computed whole) so sampling sees a replicated operand, as unsharded
     logits = shard(logits, "batch", None, "vocab_act")
     new_caches = None
     if caches is not None:

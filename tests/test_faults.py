@@ -282,6 +282,56 @@ def test_segment_exception_scrubs_batch_and_recovers(dense):
     _assert_clean(ce)
 
 
+def test_warmup_raises_segment_exception(dense):
+    """The same segment failure during ``warmup`` raises instead of being
+    scrubbed into failed requests (a program that does not compile or fit
+    would fail every request alike); after a reset the engine serves
+    exactly, and scrubbing is back on for serving."""
+    cfg, _, ce, ref = dense
+    ce.reset()
+    orig = ce._segment
+
+    def boom(*a, **k):
+        raise RuntimeError("injected compile failure")
+
+    ce._segment = boom
+    try:
+        with pytest.raises(RuntimeError, match="injected compile failure"):
+            ce.warmup([20])
+    finally:
+        ce._segment = orig
+    assert not ce._warming
+    ce.reset()
+    r = _mk(cfg.vocab, [(20, 6)])[0]
+    got = ce.run([r])
+    exp = ref.generate(r.prompt[None], r.n_new, seed=r.seed).tokens[0]
+    np.testing.assert_array_equal(got[r.rid], exp)
+    _assert_clean(ce)
+
+
+def test_serve_cli_exits_nonzero_on_failed_requests(monkeypatch):
+    """``serve.py --continuous`` exits non-zero when a request ends
+    ``failed``: here every segment after warmup raises."""
+    from repro.launch import serve
+
+    class Failing(ContinuousEngine):
+        def serve(self, workload):
+            def boom(*a, **k):
+                raise RuntimeError("injected device failure")
+            self._segment = boom
+            return super().serve(workload)
+
+    monkeypatch.setattr(serve, "ContinuousEngine", Failing)
+    monkeypatch.setattr(serve, "use_compile_cache", lambda: "")
+    with pytest.raises(SystemExit) as exc:
+        serve.main(["--arch", "stablelm_3b", "--reduced", "--continuous",
+                    "--requests", "2", "--slots", "2", "--prompt-len", "16",
+                    "--new-tokens", "6", "--seg-len", "4", "--rate", "1000"])
+    assert exc.value.code not in (0, None)
+    assert "failed" in str(exc.value.code)
+    assert "injected device failure" in str(exc.value.code)
+
+
 # -- lifecycle: cancellation --------------------------------------------------
 
 

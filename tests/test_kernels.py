@@ -149,8 +149,9 @@ def test_dsa_chunk_paged_matches_dense_kernel(rng, s, c, bq, bk):
 # With k_scale/v_scale the kernels stream an int8/fp8 cache and dequantize
 # per gathered block (row value * per-(row, head) scale) before the same
 # f32 flash loop.  Dequantizing the whole cache in XLA and running the
-# UNQUANTIZED kernel on it feeds bit-identical block values through
-# bit-identical arithmetic, so the twins must agree exactly.
+# UNQUANTIZED kernel on it feeds the same block values through the same
+# loop; the compiler may fuse the dequant multiply differently in the two
+# programs, so they agree to f32 rounding (observed <= 2e-7).
 
 
 @pytest.mark.parametrize("qd", ["int8", "fp8"])
@@ -173,7 +174,8 @@ def test_dsa_decode_quant_matches_dequant_reference(rng, s, bk, qd):
                      k_scale=ksc, v_scale=vsc)
     ref_out = dsa_decode(q, dequant(kq, ksc), dequant(vq, vsc), idx, ok,
                          kv_len, block_k=bk)
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref_out))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref_out),
+                               atol=1e-6, rtol=1e-5)
 
 
 def test_dsa_decode_paged_quant_matches_dense_quant(rng):
@@ -221,7 +223,8 @@ def test_dsa_chunk_quant_matches_dequant_reference(rng, qd):
                             block_k=bk, k_scale=ksc, v_scale=vsc)
     ref_out = dsa_chunk_prefill(q, dequant(kq, ksc), dequant(vq, vsc), idx,
                                 ok, q_off, kv_len, block_q=bq, block_k=bk)
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref_out))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref_out),
+                               atol=1e-6, rtol=1e-5)
 
 
 def test_dsa_chunk_paged_quant_matches_dense_quant(rng):
@@ -281,3 +284,20 @@ def test_wkv6_strong_decay(rng):
     assert np.isfinite(np.asarray(y)).all()
     np.testing.assert_allclose(np.asarray(y), np.asarray(yr),
                                atol=1e-3, rtol=1e-2)
+
+
+@pytest.mark.parametrize("backend,env,want", [
+    ("cpu", "", True), ("tpu", "", False), ("gpu", "1", True),
+    ("tpu", "true", True), ("gpu", "", RuntimeError)])
+def test_default_interpret_only_on_cpu(monkeypatch, backend, env, want):
+    """Kernels are interpreted on the CPU backend or when
+    JAX_PALLAS_INTERPRET asks; any other non-TPU backend raises instead of
+    silently running the interpreter."""
+    from repro.kernels import ops
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    monkeypatch.setenv("JAX_PALLAS_INTERPRET", env)
+    if want is RuntimeError:
+        with pytest.raises(RuntimeError, match="target the TPU"):
+            ops._default_interpret()
+    else:
+        assert ops._default_interpret() is want

@@ -316,7 +316,7 @@ def test_tp_sampled_chains_bitwise(dense):
     """Sampled per-slot PRNG chains with mixed temperatures under TP: the
     categorical draws replicate over "model" (vocab_act=None pins the
     logits; the draw itself runs in a replicated shard_map), so the
-    non-partitionable threefry stream is bit-identical to unsharded."""
+    threefry stream is bit-identical to unsharded."""
     cfg, params = dense
     mesh = make_serving_mesh(dp=1, tp=2, cfg=cfg)
     kw = dict(slots=SLOTS, max_len=MAX_LEN, seg_len=4)
