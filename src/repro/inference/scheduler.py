@@ -97,12 +97,30 @@ resident cache, and keeps serving the queue (``health()`` snapshots all
 of it).  With no deadlines, no queue bound, and no ``FaultInjector``
 armed, every path above is bitwise inert (pinned by tests/test_faults.py).
 
-Observability: ``ServingConfig.telemetry`` (inference.telemetry.Telemetry)
-adds request spans + a Chrome-trace timeline of chunk bursts / decode
-segments / spec rounds / faults, a Prometheus metrics registry fed from
-the same ``_emit``/``health()`` surfaces (the three can never disagree),
-a compile-event watcher that makes the recompilation contract above a
-live, CI-assertable metric, and a sampled DSA block-selection probe
+Observability: every phase of the serving loop runs inside a host span,
+``inference.telemetry.span(tel, name)``: ``serve.admit`` (``admit_ready``:
+grouping, page allocation, prefix lookup) with ``serve.admit.staging``
+(the chunked group's staging-cache build) or ``serve.admit.blocking``
+(the legacy whole-prompt admission); ``serve.chunk_burst``
+(``step_prefill``) with ``serve.insert`` (a finishing member's slot insert
+and activation); ``serve.segment`` (a decode or speculative segment) with
+``serve.segment.dispatch`` (argument copies and the jitted call),
+``serve.segment.wait`` (the host copies that block on the device) and
+``serve.segment.emit`` (per-slot emission); ``serve.wait_arrival`` (idle
+sleeps in ``serve``).  A span is always a
+``jax.profiler.TraceAnnotation``, so a profiler trace shows each phase on
+the device trace's clock (``time.time_ns``); with no profiler running it
+costs a flag check.
+``RequestResult.admit_s`` is when the request's admission group started,
+so queue wait is ``admit_s - arrival_s`` and admission to first token is
+``first_token_s - admit_s``.
+
+``ServingConfig.telemetry`` (inference.telemetry.Telemetry) adds request
+spans and records every phase span in a Chrome-trace ring on the same
+clock, a Prometheus metrics registry fed from the same ``_emit``/
+``health()`` surfaces (the three can never disagree), a compile-event
+watcher that makes the recompilation contract above a live,
+CI-assertable metric, and a sampled DSA block-selection probe
 (``_sparsity_probe``).  ``telemetry=None`` (default) is bitwise-inert —
 no wrapper, no hook, no extra dispatch (pinned by tests/test_telemetry.py).
 """
@@ -127,6 +145,7 @@ from repro.inference.engine import Engine, _ro_view, _sample, \
 from repro.inference.faults import FaultError
 from repro.inference.speculative import NGramProposer, SpeculativeDecoder, \
     can_speculate
+from repro.inference.telemetry import span
 from repro.models.attention import DSA_MODES, cache_page_size
 from repro.models.transformer import chunk_step, decode_step, init_cache, \
     unstack_group_caches, unstacked_cache_specs
@@ -237,6 +256,7 @@ class _PrefillGroup:
     dead: Set[int] = dataclasses.field(default_factory=set)
     # member indices cancelled/expired mid-chunk: their rows keep chunking
     # (the group geometry is fixed) but they never activate or emit
+    admit_s: float = 0.0          # serve clock when the group started
 
 
 def _leaf_name(path) -> Optional[str]:
@@ -503,23 +523,25 @@ class ContinuousEngine:
                 tok, caches, keys, active, remaining, finite = carry
                 logits, caches = decode_step(params, cfg, flags, tok,
                                              caches, active=active)
-                lg = logits[:, -1]
-                lg = jnp.where(poison[:, None],
-                               jnp.full_like(lg, jnp.nan), lg)
-                finite = finite & (~active | jnp.all(jnp.isfinite(lg), -1))
-                # rows shard over "data", vocab REPLICATED per row: each
-                # per-slot draw runs over its whole row locally, as in the
-                # unsharded program (no-op without a mesh)
-                lg = shard(lg, "batch", None)
-                ks = jax.vmap(jax.random.split)(keys)         # (B, 2, 2)
-                nxt_s = jax.vmap(jax.random.categorical)(
-                    ks[:, 1], lg / temps[:, None])
-                nxt_g = jnp.argmax(lg, -1)
-                nxt = jnp.where(greedy, nxt_g, nxt_s).astype(jnp.int32)
-                keys = jnp.where(greedy[:, None], keys, ks[:, 0])
-                nxt = jnp.where(active, nxt, tok[:, 0])[:, None]
-                remaining = remaining - active.astype(jnp.int32)
-                active = active & (remaining > 0)
+                with jax.named_scope("logits_sample"):
+                    lg = logits[:, -1]
+                    lg = jnp.where(poison[:, None],
+                                   jnp.full_like(lg, jnp.nan), lg)
+                    finite = finite & (~active
+                                       | jnp.all(jnp.isfinite(lg), -1))
+                    # rows shard over "data", vocab REPLICATED per row: each
+                    # per-slot draw runs over its whole row locally, as in
+                    # the unsharded program (no-op without a mesh)
+                    lg = shard(lg, "batch", None)
+                    ks = jax.vmap(jax.random.split)(keys)     # (B, 2, 2)
+                    nxt_s = jax.vmap(jax.random.categorical)(
+                        ks[:, 1], lg / temps[:, None])
+                    nxt_g = jnp.argmax(lg, -1)
+                    nxt = jnp.where(greedy, nxt_g, nxt_s).astype(jnp.int32)
+                    keys = jnp.where(greedy[:, None], keys, ks[:, 0])
+                    nxt = jnp.where(active, nxt, tok[:, 0])[:, None]
+                    remaining = remaining - active.astype(jnp.int32)
+                    active = active & (remaining > 0)
                 return (nxt, caches, keys, active, remaining, finite), \
                     nxt[:, 0]
 
@@ -540,8 +562,9 @@ class ContinuousEngine:
             logits, caches = chunk_step(params, cfg, flags, toks, caches,
                                         chunk_len, active=active,
                                         sel_len=sel_len)
-            idx = (jnp.maximum(chunk_len, 1) - 1)[:, None, None]
-            last = jnp.take_along_axis(logits, idx, axis=1)[:, 0]
+            with jax.named_scope("logits_sample"):
+                idx = (jnp.maximum(chunk_len, 1) - 1)[:, None, None]
+                last = jnp.take_along_axis(logits, idx, axis=1)[:, 0]
             return last, caches
 
         # paged twins of the insert + slot-reset machinery.  Staging caches
@@ -942,68 +965,71 @@ class ContinuousEngine:
         prompt and are discarded), so admission never recompiles per
         group; ``warmup`` precompiles both.  Every resident decoder stalls
         for the whole prompt — the cost the chunked path removes."""
-        bpf = 1 if len(group) == 1 else self.slots
-        bucket = self.engine.prompt_bucket(len(group[0].prompt))
-        mat = np.full((bpf, bucket), self.engine.pad_id, np.int32)
-        lengths = np.empty((bpf,), np.int32)
-        for j in range(bpf):
-            r = group[min(j, len(group) - 1)]
-            p = np.asarray(r.prompt, np.int32)
-            mat[j, :len(p)] = p
-            lengths[j] = len(p)
-        tel = self.telemetry
-        tt0 = tel.now() if tel is not None else 0.0
-        last, pcaches, tp = self.engine.prefill(mat, cache_len=bucket,
-                                                lengths=lengths,
-                                                dsa_mode=mode)
-        if tel is not None:
-            tel.on_admission(tt0, tp, len(group), bucket, mode,
-                             kind="blocking")
-        self.stats["prefill_s"] += tp
-        if any(s is not None for s in self._slot):
-            self.stats["stall_s"] += tp   # resident decoders sat idle
-        self.stats["admitted"] += len(group)
-        now = clock()                     # prefill has completed (blocking)
-        pcaches = unstack_group_caches(pcaches)
-        free = iter(slots)
-        for j, req in enumerate(group):
-            tok0, key = self._sample_tok0(last[j:j + 1, -1], req)
-            self.stats["useful_tokens"] += 1      # the prefill-sampled tok0
-            if req.n_new == 1:   # first token IS the whole generation
-                if self.telemetry is not None:
-                    self.telemetry.on_first_token(req.rid)
-                self._emit(results, req, np.asarray([tok0], np.int32),
-                           now, now, "ok", first_s=now)
-                continue
-            slot = next(free)
-            if self.paged:
-                # blocking + paged (archs that page but can't chunk):
-                # all-private allocation, no prefix sharing
-                npt = self._pages_needed(req)
-                pages = self.pool.alloc(npt)
-                self._zero_dirty(pages)
-                self.pool.assign_slot(slot, pages, 0)
-                row = np.zeros((self._n_kb,), np.int32)
-                row[:npt] = pages
-                with self._ctx():
-                    self._caches = self._insert_paged(
-                        self._caches, pcaches, jnp.asarray(slot, jnp.int32),
-                        jnp.asarray(j, jnp.int32), jnp.asarray(row))
-            else:
-                with self._ctx():
-                    self._caches = self._insert(
-                        self._caches, pcaches, jnp.asarray(slot, jnp.int32),
-                        jnp.asarray(j, jnp.int32))
-            self._activate(slot, req, tok0, key, now, now)
+        admit_s = clock()                 # the group's admission starts
+        with span(self.telemetry, "serve.admit.blocking") as sp:
+            bpf = 1 if len(group) == 1 else self.slots
+            bucket = self.engine.prompt_bucket(len(group[0].prompt))
+            mat = np.full((bpf, bucket), self.engine.pad_id, np.int32)
+            lengths = np.empty((bpf,), np.int32)
+            for j in range(bpf):
+                r = group[min(j, len(group) - 1)]
+                p = np.asarray(r.prompt, np.int32)
+                mat[j, :len(p)] = p
+                lengths[j] = len(p)
+            last, pcaches, tp = self.engine.prefill(mat, cache_len=bucket,
+                                                    lengths=lengths,
+                                                    dsa_mode=mode)
+            if self.telemetry is not None:
+                self.telemetry.on_admission(sp, len(group), bucket, mode,
+                                            kind="blocking")
+            self.stats["prefill_s"] += tp
+            if any(s is not None for s in self._slot):
+                self.stats["stall_s"] += tp   # resident decoders sat idle
+            self.stats["admitted"] += len(group)
+            now = clock()                 # prefill has completed (blocking)
+            pcaches = unstack_group_caches(pcaches)
+            free = iter(slots)
+            for j, req in enumerate(group):
+                tok0, key = self._sample_tok0(last[j:j + 1, -1], req)
+                self.stats["useful_tokens"] += 1  # the prefill-sampled tok0
+                if req.n_new == 1:   # first token IS the whole generation
+                    if self.telemetry is not None:
+                        self.telemetry.on_first_token(req.rid)
+                    self._emit(results, req, np.asarray([tok0], np.int32),
+                               admit_s, now, "ok", first_s=now)
+                    continue
+                slot = next(free)
+                if self.paged:
+                    # blocking + paged (archs that page but can't chunk):
+                    # all-private allocation, no prefix sharing
+                    npt = self._pages_needed(req)
+                    pages = self.pool.alloc(npt)
+                    self._zero_dirty(pages)
+                    self.pool.assign_slot(slot, pages, 0)
+                    row = np.zeros((self._n_kb,), np.int32)
+                    row[:npt] = pages
+                    with self._ctx():
+                        self._caches = self._insert_paged(
+                            self._caches, pcaches,
+                            jnp.asarray(slot, jnp.int32),
+                            jnp.asarray(j, jnp.int32), jnp.asarray(row))
+                else:
+                    with self._ctx():
+                        self._caches = self._insert(
+                            self._caches, pcaches,
+                            jnp.asarray(slot, jnp.int32),
+                            jnp.asarray(j, jnp.int32))
+                self._activate(slot, req, tok0, key, admit_s, now)
 
     # -- chunked admission (default) ----------------------------------------
 
     def _start_chunked_group(self, free: List[int], group: List[Request],
-                             mode: str) -> None:
+                             mode: str, clock) -> None:
         """Begin streaming a same-bucket group through a fresh bucket-sized
         staging cache; resident slots are reserved now, filled at group
         completion.  Two staging widths per bucket (1 / ``slots``), like
         the legacy path, so the chunk program set stays fixed."""
+        admit_s = clock()                 # the group's admission starts
         bucket = self.engine.prompt_bucket(len(group[0].prompt))
         c = min(self.chunk_tokens, pow2_bucket(bucket, self._chunk_floor))
         bpf = 1 if len(group) == 1 else self.slots
@@ -1015,9 +1041,6 @@ class ContinuousEngine:
             p = np.asarray(r.prompt, np.int32)
             mat[j, :len(p)] = p
             lengths[j] = len(p)
-        caches = self._put_cache(unstack_group_caches(
-            init_cache(self.cfg, bpf, bucket, self.engine.decode_flags,
-                       dtype=self.engine.cache_dtype)))
         slots = []
         it = iter(free)
         for r in group:
@@ -1027,6 +1050,7 @@ class ContinuousEngine:
             slots.append(slot)
         tbls = None
         skip = 0
+        shared = None
         if self.paged:
             key, n_sh = self._prefix_ctx(group[0], bucket, mode, True)
             shared = self.pool.lookup_prefix(key) if key else None
@@ -1070,23 +1094,26 @@ class ContinuousEngine:
                 # bytes chunking [0, skip*c) would have written.
                 skip = min(n_sh * self._page_rows // c,
                            min(-(-len(r.prompt) // c) for r in group) - 1)
-                if skip > 0:
-                    rpages = jnp.asarray(
-                        shared[:skip * c // self._page_rows], jnp.int32)
-                    with self._ctx():
-                        caches = self._seed(caches, self._caches, rpages,
-                                            skip * c)
                 self.stats["prefix_hits"] += len(group)
                 self.stats["prefix_tokens_reused"] += skip * c * len(group)
+        with span(self.telemetry, "serve.admit.staging") as sp:
+            caches = self._put_cache(unstack_group_caches(
+                init_cache(self.cfg, bpf, bucket, self.engine.decode_flags,
+                           dtype=self.engine.cache_dtype)))
+            if skip > 0:
+                rpages = jnp.asarray(
+                    shared[:skip * c // self._page_rows], jnp.int32)
+                with self._ctx():
+                    caches = self._seed(caches, self._caches, rpages,
+                                        skip * c)
+            if self.telemetry is not None:
+                self.telemetry.on_admission(sp, len(group), bucket, mode,
+                                            kind="chunked",
+                                            prefix_skip_chunks=skip)
         self._pf = _PrefillGroup(group, slots, bucket, c, mode, caches,
                                  lengths, j=skip, n_chunks=n_chunks, mat=mat,
-                                 tbls=tbls)
+                                 tbls=tbls, admit_s=admit_s)
         self.stats["admitted"] += len(group)
-        if self.telemetry is not None:
-            self.telemetry.on_admission(self.telemetry.now(), 0.0,
-                                        len(group), bucket, mode,
-                                        kind="chunked",
-                                        prefix_skip_chunks=skip)
 
     def _chunk_burst(self) -> int:
         """How many chunks to run before yielding to a decode segment.
@@ -1121,6 +1148,14 @@ class ContinuousEngine:
         pf = self._pf
         if pf is None:
             return
+        with span(self.telemetry, "serve.chunk_burst") as sp:
+            self._run_chunks(pf, clock, results, sp)
+        if pf.j >= pf.n_chunks:
+            self._pf = None               # all members inserted already
+
+    def _run_chunks(self, pf: _PrefillGroup, clock,
+                    results: List[RequestResult], sp: span) -> None:
+        """The chunk burst of ``step_prefill`` (inside its span)."""
         bpf = pf.lengths.shape[0]
         active = self._put_b(np.ones((bpf,), bool))
         flags = self._flags(pf.mode)
@@ -1155,23 +1190,24 @@ class ContinuousEngine:
                     if self.telemetry is not None:
                         self.telemetry.on_first_token(req.rid)
                     self._emit(results, req, np.asarray([tok0], np.int32),
-                               now, now, "ok", first_s=now)
+                               pf.admit_s, now, "ok", first_s=now)
                     continue
                 slot = pf.slots[i]        # early activation: decode NOW
-                with self._ctx():
-                    if self.paged:
-                        self._caches = self._insert_paged(
-                            self._caches, pf.caches,
-                            jnp.asarray(slot, jnp.int32),
-                            jnp.asarray(i, jnp.int32),
-                            jnp.asarray(pf.tbls[i]))
-                    else:
-                        self._caches = self._insert(
-                            self._caches, pf.caches,
-                            jnp.asarray(slot, jnp.int32),
-                            jnp.asarray(i, jnp.int32))
-                self._reserved.discard(slot)
-                self._activate(slot, req, tok0, key, now, now)
+                with span(self.telemetry, "serve.insert"):
+                    with self._ctx():
+                        if self.paged:
+                            self._caches = self._insert_paged(
+                                self._caches, pf.caches,
+                                jnp.asarray(slot, jnp.int32),
+                                jnp.asarray(i, jnp.int32),
+                                jnp.asarray(pf.tbls[i]))
+                        else:
+                            self._caches = self._insert(
+                                self._caches, pf.caches,
+                                jnp.asarray(slot, jnp.int32),
+                                jnp.asarray(i, jnp.int32))
+                    self._reserved.discard(slot)
+                    self._activate(slot, req, tok0, key, pf.admit_s, now)
         if not synced:
             jax.block_until_ready(jax.tree.leaves(pf.caches)[0])
         dt = time.monotonic() - t0
@@ -1180,16 +1216,19 @@ class ContinuousEngine:
         if stalled:
             self.stats["stall_s"] += dt
         if self.telemetry is not None:
-            self.telemetry.on_chunk_burst(dt, burst, pf.bucket, pf.mode,
+            self.telemetry.on_chunk_burst(sp, dt, burst, pf.bucket, pf.mode,
                                           len(pf.reqs))
-        if pf.j >= pf.n_chunks:
-            self._pf = None               # all members inserted already
 
     def admit_ready(self, clock, results: List[RequestResult]) -> None:
         """``clock``: zero-arg callable giving seconds since serve start;
         admission/finish timestamps are sampled AFTER blocking work.
         Chunked mode only STARTS a group here (one in flight at a time) —
         its chunks run via ``step_prefill`` between decode segments."""
+        with span(self.telemetry, "serve.admit"):
+            self._admit(clock, results)
+
+    def _admit(self, clock, results: List[RequestResult]) -> None:
+        """The body of ``admit_ready`` (inside its span)."""
         if self._pending:
             # results emitted outside a results-carrying call (submit-time
             # sheds, cancel(), unfundable sheds) surface at the next
@@ -1215,7 +1254,7 @@ class ContinuousEngine:
             # envelope (DSA-over-MLA): such groups fall back to blocking
             if self.chunked and can_chunk_prefill(
                     self.cfg, mode, moe_dense=self.engine.moe_dense):
-                self._start_chunked_group(free, group, mode)
+                self._start_chunked_group(free, group, mode, clock)
                 break
             self._admit_group(free, group, mode, clock, results)
 
@@ -1330,7 +1369,7 @@ class ContinuousEngine:
             for i, r in enumerate(pf.reqs):
                 if i not in pf.dead and expired(r):
                     self._emit(results, r, np.zeros((0,), np.int32),
-                               now, now, "timeout")
+                               pf.admit_s, now, "timeout")
                     self._kill_pf_member(pf, i)
         for i, st in enumerate(self._slot):
             if st is not None and expired(st.req):
@@ -1395,7 +1434,7 @@ class ContinuousEngine:
             for i, r in enumerate(pf.reqs):
                 if i not in pf.dead:
                     self._emit(results, r, np.zeros((0,), np.int32),
-                               now, now, "failed")
+                               pf.admit_s, now, "failed")
             self._pf = None
         self._reserved.clear()
         self._init_resident()
@@ -1593,6 +1632,26 @@ class ContinuousEngine:
                 flags=self._flags(self.engine.decode_flags.dsa_mode))
         return lowered.compile().as_text()
 
+    def chunk_hlo(self, bucket: int, width: Optional[int] = None) -> str:
+        """Compiled HLO text of the chunk program chunked admission runs
+        for prompt bucket ``bucket`` at staging width ``width`` (1 or
+        ``slots``, the default)."""
+        bucket = self.engine.prompt_bucket(bucket)
+        c = min(self.chunk_tokens, pow2_bucket(bucket, self._chunk_floor))
+        bpf = width or self.slots
+        caches = self._put_cache(unstack_group_caches(
+            init_cache(self.cfg, bpf, bucket, self.engine.decode_flags,
+                       dtype=self.engine.cache_dtype)))
+        with self._ctx():
+            lowered = self._chunk.lower(
+                self.engine.params, caches,
+                self._put_b(np.zeros((bpf, c), np.int32)),
+                self._put_b(np.zeros((bpf,), np.int32)),
+                self._put_b(np.ones((bpf,), bool)),
+                flags=self._flags(self.engine.decode_flags.dsa_mode),
+                sel_len=bucket)
+        return lowered.compile().as_text()
+
     # -- decode segments ----------------------------------------------------
 
     def run_segment(self, clock,
@@ -1613,6 +1672,20 @@ class ContinuousEngine:
                 # untouched — the serving loop simply retries next round
                 self.stats["dispatch_failures"] += 1
                 return
+        with span(self.telemetry, "serve.segment") as sp:
+            ran = self._segment_body(clock, results, remaining, mode,
+                                     poison, sp)
+        if (ran and self._pf is None
+                and not any(s is not None for s in self._slot)):
+            self._cur_mode = None         # idle: free to switch dsa_mode
+
+    def _segment_body(self, clock, results: List[RequestResult], remaining,
+                      mode: str, poison, sp: span) -> bool:
+        """Dispatch, wait for and emit one decode segment (inside the
+        ``serve.segment`` span of ``run_segment``); False when the
+        dispatch failed and the in-flight batch was scrubbed."""
+        inj = self.injector
+        tel = self.telemetry
         t0 = time.monotonic()
         self._watchdog.start()
         if inj is not None:
@@ -1620,7 +1693,7 @@ class ContinuousEngine:
             if f is not None:
                 time.sleep(f.delay_s)   # stall INSIDE the watchdog window
         try:
-            with self._ctx():
+            with span(tel, "serve.segment.dispatch"), self._ctx():
                 tok, caches, keys, active, rem, fin, toks = self._segment(
                     self.engine.params, self._put_b(self._tok),
                     self._caches, self._put_b(self._keys),
@@ -1628,11 +1701,12 @@ class ContinuousEngine:
                     self._put_b(self._temps), self._put_b(remaining),
                     self._put_b(poison), flags=self._flags(mode))
             self._caches = caches
-            self._tok = np.array(tok)       # np.array: writable host copies
-            self._keys = np.array(keys)
-            self._active = np.array(active)
-            fin = np.asarray(fin)
-            toks = np.asarray(toks)                   # (slots, seg_len)
+            with span(tel, "serve.segment.wait"):
+                self._tok = np.array(tok)   # np.array: writable host copies
+                self._keys = np.array(keys)
+                self._active = np.array(active)
+                fin = np.asarray(fin)
+                toks = np.asarray(toks)               # (slots, seg_len)
         except Exception as e:              # noqa: BLE001 — fail partially
             if self._warming:
                 raise
@@ -1641,10 +1715,10 @@ class ContinuousEngine:
             # keep serving the queue
             self._last_error = repr(e)
             self.stats["dispatch_failures"] += 1
-            if self.telemetry is not None:
-                self.telemetry.on_error(repr(e))
+            if tel is not None:
+                tel.on_error(repr(e))
             self._scrub_all(clock, results)
-            return
+            return False
         now = clock()                     # host copies above synced the step
         self.stats["segments"] += 1
         seg_wall = time.monotonic() - t0
@@ -1654,35 +1728,38 @@ class ContinuousEngine:
             self.stats["watchdog_slow"] += 1
         ut0 = self.stats["useful_tokens"]
         n_act = sum(s is not None for s in self._slot)
-        for i, st in enumerate(self._slot):
-            if st is None:
-                continue
-            if not fin[i]:
-                # non-finite logits row: this slot's sampled tokens are
-                # garbage from the first bad step on — fail ONLY this slot
-                # with its pre-segment tokens (co-resident rows never read
-                # another row's logits, so they are bitwise unaffected)
-                self._last_error = (f"request {st.req.rid}: non-finite "
-                                    f"logits row in decode segment")
-                self._emit(results, st.req, self._partial(st), st.admit_s,
-                           now, "failed", first_s=st.first_token_s)
-                self._retire_slot(i)
-                continue
-            emitted = min(st.remaining, self.seg_len)
-            st.collected.append(toks[i, :emitted])
-            st.extend_history(toks[i, :emitted])
-            st.remaining -= emitted
-            self.stats["useful_tokens"] += emitted
-            if st.remaining == 0:
-                self._emit(results, st.req, self._partial(st), st.admit_s,
-                           now, "ok", first_s=st.first_token_s)
-                self._slot[i] = None          # slot freed; reset at admit
-                if self.paged:
-                    self.pool.free_slot(i)    # non-shared pages return
-        tel = self.telemetry
+        with span(tel, "serve.segment.emit"):
+            for i, st in enumerate(self._slot):
+                if st is None:
+                    continue
+                if not fin[i]:
+                    # non-finite logits row: this slot's sampled tokens are
+                    # garbage from the first bad step on — fail ONLY this
+                    # slot with its pre-segment tokens (co-resident rows
+                    # never read another row's logits, so they are bitwise
+                    # unaffected)
+                    self._last_error = (f"request {st.req.rid}: non-finite "
+                                        f"logits row in decode segment")
+                    self._emit(results, st.req, self._partial(st),
+                               st.admit_s, now, "failed",
+                               first_s=st.first_token_s)
+                    self._retire_slot(i)
+                    continue
+                emitted = min(st.remaining, self.seg_len)
+                st.collected.append(toks[i, :emitted])
+                st.extend_history(toks[i, :emitted])
+                st.remaining -= emitted
+                self.stats["useful_tokens"] += emitted
+                if st.remaining == 0:
+                    self._emit(results, st.req, self._partial(st),
+                               st.admit_s, now, "ok",
+                               first_s=st.first_token_s)
+                    self._slot[i] = None      # slot freed; reset at admit
+                    if self.paged:
+                        self.pool.free_slot(i)  # non-shared pages return
         if tel is not None:
             tel.on_segment(
-                "decode_segment", seg_wall, mode=mode, active=n_act,
+                sp, "decode_segment", seg_wall, mode=mode, active=n_act,
                 tokens=self.stats["useful_tokens"] - ut0,
                 queued=len(self.queue),
                 resident=sum(s is not None for s in self._slot),
@@ -1691,8 +1768,7 @@ class ContinuousEngine:
             if (tel.sample_every
                     and self.stats["segments"] % tel.sample_every == 0):
                 self._sparsity_probe(mode)
-        if self._pf is None and not any(s is not None for s in self._slot):
-            self._cur_mode = None         # idle: free to switch dsa_mode
+        return True
 
     # -- dynamic-sparsity sampling ------------------------------------------
 
@@ -1776,6 +1852,22 @@ class ContinuousEngine:
         flags = dataclasses.replace(
             self._flags(self._cur_mode or self.engine.decode_flags.dsa_mode),
             spec_verify=True)
+        with span(self.telemetry, "serve.segment") as sp:
+            rounds_run = self._spec_rounds(clock, results, flags, sp)
+        if not rounds_run and any(s is not None for s in self._slot):
+            # the proposer crashed before any verify round: this segment
+            # degrades to a plain fused segment so resident slots still
+            # make progress (same tokens — spec == plain bitwise)
+            self.run_segment(clock, results)
+            return
+        if self._pf is None and not any(s is not None for s in self._slot):
+            self._cur_mode = None         # idle: free to switch dsa_mode
+
+    def _spec_rounds(self, clock, results: List[RequestResult], flags,
+                     sp: span) -> int:
+        """The verify rounds of ``run_spec_segment`` (inside its
+        ``serve.segment`` span); returns how many ran."""
+        tel = self.telemetry
         t0 = time.monotonic()
         self._watchdog.start()
         draft_s0 = self.stats["draft_s"]
@@ -1799,8 +1891,8 @@ class ContinuousEngine:
             except Exception as e:          # noqa: BLE001 — degrade, don't die
                 # a crashing proposer only ever costs SPEED: spec segments
                 # are bitwise plain decode, so this segment falls back to a
-                # plain fused segment (below) and repeated failures stop
-                # consulting the proposer entirely
+                # plain fused segment (run_spec_segment) and repeated
+                # failures stop consulting the proposer entirely
                 self.stats["draft_s"] += time.monotonic() - td
                 self.stats["proposer_failures"] += 1
                 self._last_error = repr(e)
@@ -1811,7 +1903,7 @@ class ContinuousEngine:
             self.stats["draft_s"] += time.monotonic() - td
             remaining = np.asarray(
                 [st.remaining if st else 0 for st in self._slot], np.int32)
-            with self._ctx():
+            with span(tel, "serve.segment.dispatch"), self._ctx():
                 tok, caches, keys, nxt, emit, _, act2 = self._spec.verify(
                     self.engine.params, self._put_b(self._tok),
                     self._put_b(drafts), self._caches,
@@ -1819,32 +1911,16 @@ class ContinuousEngine:
                     self._put_b(self._greedy), self._put_b(self._temps),
                     self._put_b(remaining), flags=flags)
             self._caches = caches
-            self._tok = np.array(tok)     # np.array: writable host copies
-            self._keys = np.array(keys)
-            self._active = np.array(act2)
-            emit_np, nxt_np = np.asarray(emit), np.asarray(nxt)
+            with span(tel, "serve.segment.wait"):
+                self._tok = np.array(tok)   # np.array: writable host copies
+                self._keys = np.array(keys)
+                self._active = np.array(act2)
+                emit_np, nxt_np = np.asarray(emit), np.asarray(nxt)
             now = clock()                 # host copies above synced the round
             self.stats["spec_rounds"] += 1
             rounds_run += 1
-            for i, st in enumerate(self._slot):
-                if st is None:
-                    continue
-                e = int(emit_np[i])
-                if e == 0:
-                    continue
-                st.collected.append(nxt_np[i, :e].astype(np.int32))
-                st.extend_history(nxt_np[i, :e].astype(np.int32))
-                st.remaining -= e
-                self.stats["useful_tokens"] += e
-                self.stats["spec_emitted"] += e
-                self.stats["accept_hist"][e - 1] += 1
-                if st.remaining == 0:
-                    self._emit(results, st.req, self._partial(st),
-                               st.admit_s, now, "ok",
-                               first_s=st.first_token_s)
-                    self._slot[i] = None  # slot freed; reset at admit
-                    if self.paged:
-                        self.pool.free_slot(i)
+            with span(tel, "serve.segment.emit"):
+                self._emit_spec_round(results, emit_np, nxt_np, now)
         # stats feed the chunk-burst budget tuner (_chunk_burst): count a
         # segment only when rounds actually ran, and report DEVICE segment
         # time — host drafting excluded — so the tuner sizes admission
@@ -1857,23 +1933,39 @@ class ContinuousEngine:
             slow = self._watchdog.stop(self.stats["segments"])
             if slow:
                 self.stats["watchdog_slow"] += 1
-            if self.telemetry is not None:
-                self.telemetry.on_segment(
-                    "spec_segment", seg_dev,
+            if tel is not None:
+                tel.on_segment(
+                    sp, "spec_segment", seg_dev,
                     mode=flags.dsa_mode,
                     active=sum(s is not None for s in self._slot),
                     tokens=self.stats["useful_tokens"] - ut0,
                     queued=len(self.queue),
                     resident=sum(s is not None for s in self._slot),
                     slow=slow, rounds=rounds_run)
-        elif any(s is not None for s in self._slot):
-            # the proposer crashed before any verify round: this segment
-            # degrades to a plain fused segment so resident slots still
-            # make progress (same tokens — spec == plain bitwise)
-            self.run_segment(clock, results)
-            return
-        if self._pf is None and not any(s is not None for s in self._slot):
-            self._cur_mode = None         # idle: free to switch dsa_mode
+        return rounds_run
+
+    def _emit_spec_round(self, results: List[RequestResult], emit_np,
+                         nxt_np, now: float) -> None:
+        """Collect each slot's accepted tokens of one verify round."""
+        for i, st in enumerate(self._slot):
+            if st is None:
+                continue
+            e = int(emit_np[i])
+            if e == 0:
+                continue
+            st.collected.append(nxt_np[i, :e].astype(np.int32))
+            st.extend_history(nxt_np[i, :e].astype(np.int32))
+            st.remaining -= e
+            self.stats["useful_tokens"] += e
+            self.stats["spec_emitted"] += e
+            self.stats["accept_hist"][e - 1] += 1
+            if st.remaining == 0:
+                self._emit(results, st.req, self._partial(st),
+                           st.admit_s, now, "ok",
+                           first_s=st.first_token_s)
+                self._slot[i] = None  # slot freed; reset at admit
+                if self.paged:
+                    self.pool.free_slot(i)
 
     def _step_decode(self, clock, results: List[RequestResult]) -> None:
         """One decode segment at the current mode: speculative when the
@@ -1925,12 +2017,14 @@ class ContinuousEngine:
             if any(s is not None for s in self._slot):
                 self._step_decode(clock, results)
             elif self._pf is None and not self.queue and i < len(items):
-                time.sleep(max(0.0, min(items[i].arrival_s - now, 0.05)))
+                with span(self.telemetry, "serve.wait_arrival"):
+                    time.sleep(max(0.0, min(items[i].arrival_s - now, 0.05)))
             elif self._pf is None and self.queue and self._unfundable:
                 # page-budget-unfundable anchor with nothing else to do:
                 # bounded exponential backoff instead of a busy spin
                 n = max(self._unfundable.values())
-                time.sleep(min(0.001 * (1 << min(n, 6)), 0.05))
+                with span(self.telemetry, "serve.wait_arrival"):
+                    time.sleep(min(0.001 * (1 << min(n, 6)), 0.05))
         results.extend(self._pending)
         self._pending.clear()
         return sorted(results, key=lambda r: r.rid)

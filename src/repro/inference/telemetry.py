@@ -8,8 +8,9 @@ module.  With telemetry enabled there are four layers:
 
   request spans      every ``Request`` gets timestamped lifecycle events
                      (submit -> first token -> retire-with-status) and
-                     the engine emits chunk-burst / decode-segment /
-                     spec-verify / admission / fault events into a
+                     the engine's phase spans (``serve.admit``,
+                     ``serve.chunk_burst``, ``serve.segment`` and their
+                     children; see ``span``) and fault events land in a
                      bounded ring buffer, exportable as Chrome
                      trace-event JSON (load in Perfetto or
                      chrome://tracing).
@@ -45,9 +46,20 @@ only extra device work and it is sampled.  The traced-vs-untraced
 goodput ratio is benchmarked (``table_serve``: ``continuous_traced``)
 and regression-gated at >= 0.95 on full runs.
 
-Trace timestamps use the telemetry object's own monotonic epoch (first
-event = t0), independent of the engine's serve clock, so engine events
-and request spans share one timeline.
+Host spans: the scheduler opens every phase of its loop through
+``span(tel, name, **args)``.  A span always enters
+``jax.profiler.TraceAnnotation(name)``, so while a ``jax.profiler`` trace
+runs the phase lands in its ``/host:CPU`` plane on the device trace's
+clock; with no profiler running that costs one flag check.  With a
+Telemetry bound (``tel`` not None) the span also records the same
+interval as a complete event in the Chrome-trace ring.
+
+Clock: ``Telemetry.now`` and every Chrome-trace timestamp read
+``time.time_ns()``, the clock the profiler's ``TraceMe`` events use: a
+``ts`` is microseconds since the Unix epoch (``otherData`` in the export
+says so), and an event at ``ts`` lies ``ts * 1e3 -
+profile_start_time`` ns into a profiler trace whose ``Task Environment``
+plane records that ``profile_start_time``.
 
 ``reset()`` (called from ``ContinuousEngine.reset()``) clears events,
 spans, and metrics but KEEPS the compile log: compiled programs survive
@@ -61,9 +73,10 @@ from collections import deque
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
+from jax.profiler import TraceAnnotation
 
 __all__ = ["Counter", "Gauge", "Histogram", "MetricsRegistry",
-           "Telemetry"]
+           "Telemetry", "span"]
 
 # default histogram bucket bounds (seconds / ratios)
 LATENCY_BUCKETS = (0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1,
@@ -229,6 +242,38 @@ class _CompileWatch:
 
 
 # ---------------------------------------------------------------------------
+# host spans
+
+
+class span:
+    """``with span(tel, name, **args) as sp:`` -- one phase of the serving
+    loop.  Always a ``jax.profiler.TraceAnnotation(name)`` (a flag check
+    when no profiler runs); with a Telemetry ``tel`` also a complete event
+    ``name`` in its Chrome-trace ring, carrying ``sp.args`` as they stand
+    when the span closes (callers may add to them inside)."""
+
+    __slots__ = ("tel", "name", "args", "t0_ns", "_ann")
+
+    def __init__(self, tel: Optional["Telemetry"], name: str, **args):
+        self.tel, self.name, self.args = tel, name, args
+
+    def __enter__(self) -> "span":
+        self._ann = TraceAnnotation(self.name)
+        self._ann.__enter__()
+        if self.tel is not None:
+            self.t0_ns = time.time_ns()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self._ann.__exit__(*exc)
+        if self.tel is not None:
+            t1 = time.time_ns()
+            self.tel.complete(self.name, self.t0_ns * 1e-9,
+                              (t1 - self.t0_ns) * 1e-9, tid="serve",
+                              args=self.args or None)
+
+
+# ---------------------------------------------------------------------------
 # telemetry
 
 
@@ -241,18 +286,16 @@ class Telemetry:
         self.metrics = MetricsRegistry()
         self.events: deque = deque(maxlen=int(max_events))
         self.compiles: List[Tuple[str, tuple, float]] = []
-        self._t0: Optional[float] = None
         self._spans: Dict[int, float] = {}      # rid -> submit ts (s)
         self._engine: Any = None
 
     # -- clock / raw events -------------------------------------------------
 
-    def now(self) -> float:
-        """Seconds since this Telemetry's first event (own epoch)."""
-        t = time.monotonic()
-        if self._t0 is None:
-            self._t0 = t
-        return t - self._t0
+    @staticmethod
+    def now() -> float:
+        """Seconds since the Unix epoch on the profiler's clock
+        (``time.time_ns``)."""
+        return time.time_ns() * 1e-9
 
     def _ev(self, name, ph, ts, pid, tid, dur=None, args=None):
         e = {"name": name, "ph": ph, "ts": ts * 1e6, "pid": pid,
@@ -305,6 +348,8 @@ class Telemetry:
                 len(res.tokens))
             self.metrics.histogram("serving_request_latency_seconds"
                                    ).observe(res.latency_s)
+            self.metrics.histogram("serving_queue_wait_seconds").observe(
+                res.admit_s - res.arrival_s)
         self.complete(f"req {res.rid} [{res.status}]", t0, t - t0,
                       pid="requests", tid=f"rid {res.rid}",
                       args={"status": res.status,
@@ -314,28 +359,30 @@ class Telemetry:
 
     # -- engine timeline ----------------------------------------------------
 
-    def on_admission(self, ts, dur_s, n, bucket, mode, kind,
+    def on_admission(self, sp: span, n, bucket, mode, kind,
                      prefix_skip_chunks=0) -> None:
+        """An admission group started; ``sp`` is the span that builds it
+        (``serve.admit.staging`` or ``serve.admit.blocking``), whose event
+        carries the group's shape."""
         self.metrics.counter("serving_admissions_total", kind=kind).inc(n)
-        args = {"n": n, "bucket": int(bucket), "mode": mode}
+        sp.args.update(n=n, bucket=int(bucket), mode=mode, kind=kind)
         if prefix_skip_chunks:
-            args["prefix_skip_chunks"] = int(prefix_skip_chunks)
-        if dur_s > 0:
-            self.complete(f"admit[{kind}] x{n}", ts, dur_s,
-                          tid="admission", args=args)
-        else:
-            self.instant(f"admit[{kind}] x{n}", tid="admission", args=args)
+            sp.args["prefix_skip_chunks"] = int(prefix_skip_chunks)
 
-    def on_chunk_burst(self, dur_s, chunks, bucket, mode, members) -> None:
+    def on_chunk_burst(self, sp: span, dur_s, chunks, bucket, mode,
+                       members) -> None:
+        """``dur_s``: the burst's synced wall time (``stats["chunk_s"]``);
+        ``sp`` is its ``serve.chunk_burst`` span."""
         self.metrics.counter("serving_chunks_total").inc(chunks)
         self.metrics.histogram("serving_chunk_burst_seconds").observe(dur_s)
-        self.complete(f"chunk_burst x{chunks}", self.now() - dur_s, dur_s,
-                      tid="admission",
-                      args={"chunks": chunks, "bucket": int(bucket),
-                            "mode": mode, "members": members})
+        sp.args.update(chunks=chunks, bucket=int(bucket), mode=mode,
+                       members=members)
 
-    def on_segment(self, kind, dur_s, *, mode, active, tokens, queued,
-                   resident, pool_free=None, slow=False, rounds=0) -> None:
+    def on_segment(self, sp: span, kind, dur_s, *, mode, active, tokens,
+                   queued, resident, pool_free=None, slow=False,
+                   rounds=0) -> None:
+        """``dur_s``: the segment's synced wall time
+        (``stats["segment_s"]``); ``sp`` is its ``serve.segment`` span."""
         m = self.metrics
         m.counter("serving_segments_total", kind=kind).inc()
         m.counter("serving_segment_tokens_total").inc(tokens)
@@ -348,13 +395,12 @@ class Telemetry:
             m.counter("serving_watchdog_slow_total").inc()
         if rounds:
             m.counter("serving_spec_rounds_total").inc(rounds)
-        args = {"mode": mode, "active": int(active), "tokens": int(tokens)}
+        sp.args.update(kind=kind, mode=mode, active=int(active),
+                       tokens=int(tokens))
         if rounds:
-            args["verify_rounds"] = int(rounds)
+            sp.args["verify_rounds"] = int(rounds)
         if slow:
-            args["watchdog_slow"] = True
-        self.complete(kind, self.now() - dur_s, dur_s, tid="segments",
-                      args=args)
+            sp.args["watchdog_slow"] = True
 
     def on_fault(self, point: str, rid=None) -> None:
         self.metrics.counter("serving_faults_total", point=point).inc()
@@ -439,7 +485,9 @@ class Telemetry:
             meta.append({"name": "thread_name", "ph": "M", "pid": pid,
                          "tid": tid, "args": {"name": str(tid)}})
         return {"traceEvents": meta + list(self.events),
-                "displayTimeUnit": "ms"}
+                "displayTimeUnit": "ms",
+                "otherData": {"clock": "time.time_ns",
+                              "ts": "microseconds since the Unix epoch"}}
 
     def write_chrome_trace(self, path: str) -> None:
         with open(path, "w") as f:

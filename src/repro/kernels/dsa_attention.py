@@ -116,6 +116,6 @@ def dsa_block_sparse_attention(q, k, v, idx, valid, *, block_q: int = 128,
     fn = pl.pallas_call(
         kern, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hq, lq, hd), q.dtype),
-        interpret=interpret,
+        interpret=interpret, name="dsa_attention",
     )
     return fn(idx, valid.astype(jnp.int32), q, k, v)

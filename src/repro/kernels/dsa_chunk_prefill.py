@@ -112,10 +112,11 @@ def _kernel(*refs, block_q: int, block_k: int, nb: int, n_kv: int,
 
 
 def _call(q, k, v, k_scale, v_scale, prefetch, block_row, *, block_q,
-          block_k, interpret):
+          block_k, interpret, name):
     """One pallas_call for the dense and paged variants.  ``prefetch`` is
     (idx, ok, q_off, kv_len[, pidx]); ``block_row(b, qb, j, *refs)`` gives
-    the (batch, block) coordinates of the cache row-block to stream."""
+    the (batch, block) coordinates of the cache row-block to stream;
+    ``name`` names the kernel in compiled programs and profiles."""
     b, c, hq, hd = q.shape
     hkv = k.shape[2]
     nb = prefetch[0].shape[-1]
@@ -161,7 +162,7 @@ def _call(q, k, v, k_scale, v_scale, prefetch, block_row, *, block_q,
     fn = pl.pallas_call(
         kern, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hq, c, hd), q.dtype),
-        interpret=interpret,
+        interpret=interpret, name=name,
     )
     out = fn(*(p.astype(jnp.int32) for p in prefetch), *args)
     return out.transpose(0, 2, 1, 3)
@@ -192,7 +193,8 @@ def dsa_chunk_paged_gather_attention(q, k_pool, v_pool, idx, pidx, ok,
                  k_scale[None] if quant else None,
                  v_scale[None] if quant else None,
                  (idx, ok, q_off, kv_len, pidx), block_row, block_q=block_q,
-                 block_k=block_k, interpret=interpret)
+                 block_k=block_k, interpret=interpret,
+                 name="dsa_chunk_prefill_paged")
 
 
 def dsa_chunk_gather_attention(q, k_cache, v_cache, idx, ok, q_off, kv_len,
@@ -217,4 +219,5 @@ def dsa_chunk_gather_attention(q, k_cache, v_cache, idx, ok, q_off, kv_len,
 
     return _call(q, k_cache, v_cache, k_scale, v_scale,
                  (idx, ok, q_off, kv_len), block_row, block_q=block_q,
-                 block_k=block_k, interpret=interpret)
+                 block_k=block_k, interpret=interpret,
+                 name="dsa_chunk_prefill")

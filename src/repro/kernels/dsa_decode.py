@@ -101,10 +101,11 @@ def _kernel(*refs, block_k: int, nb: int, n_kv: int, scale: float,
 
 
 def _call(q, k, v, k_scale, v_scale, prefetch, block_row, *, block_k,
-          interpret):
+          interpret, name):
     """One pallas_call for the dense and paged variants.  ``prefetch`` is
     (idx, ok, kv_len[, pidx]); ``block_row(b, j, *prefetch_refs)`` gives the
-    (batch, block) coordinates of the cache row-block to stream."""
+    (batch, block) coordinates of the cache row-block to stream; ``name``
+    names the kernel in compiled programs and profiles."""
     b, _, hq, hd = q.shape
     hkv = k.shape[2]
     nb = prefetch[0].shape[-1]
@@ -143,7 +144,7 @@ def _call(q, k, v, k_scale, v_scale, prefetch, block_row, *, block_k,
     fn = pl.pallas_call(
         kern, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, 1, hq, hd), q.dtype),
-        interpret=interpret,
+        interpret=interpret, name=name,
     )
     return fn(*(p.astype(jnp.int32) for p in prefetch), *args)
 
@@ -172,7 +173,7 @@ def dsa_decode_paged_gather_attention(q, k_pool, v_pool, idx, pidx, ok,
                  k_scale[None] if quant else None,
                  v_scale[None] if quant else None,
                  (idx, ok, kv_len, pidx), block_row, block_k=block_k,
-                 interpret=interpret)
+                 interpret=interpret, name="dsa_decode_paged")
 
 
 def dsa_decode_gather_attention(q, k_cache, v_cache, idx, ok, kv_len, *,
@@ -195,4 +196,5 @@ def dsa_decode_gather_attention(q, k_cache, v_cache, idx, ok, kv_len, *,
         return (bi, idx_ref[bi, ji])
 
     return _call(q, k_cache, v_cache, k_scale, v_scale, (idx, ok, kv_len),
-                 block_row, block_k=block_k, interpret=interpret)
+                 block_row, block_k=block_k, interpret=interpret,
+                 name="dsa_decode")

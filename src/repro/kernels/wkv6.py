@@ -95,6 +95,6 @@ def wkv6_chunked(r, k, v, w, u, *, chunk: int = 32,
         out_specs=pl.BlockSpec((1, 1, chunk, hd), xmap),
         out_shape=jax.ShapeDtypeStruct((b, h, s, hd), r.dtype),
         scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret, name="wkv6",
     )
     return fn(r, k, v, w, u)

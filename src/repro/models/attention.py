@@ -508,35 +508,40 @@ def _apply_decode(params, cfg: ArchConfig, flags: RunFlags, x, cache,
     """
     b = x.shape[0]
     pos = _slot_pos(cache, b)                              # (B,)
-    q, k, v = _proj_qkv(params, cfg, x)
-    if use_rope:
-        p = pos[:, None]                                   # per-row positions
-        q = rope(q, p, cfg.rope_theta)
-        k = rope(k, p, cfg.rope_theta)
+    with jax.named_scope("qkv"):
+        q, k, v = _proj_qkv(params, cfg, x)
+        if use_rope:
+            p = pos[:, None]                               # per-row positions
+            q = rope(q, p, cfg.rope_theta)
+            k = rope(k, p, cfg.rope_theta)
     # slot-axis data parallelism (serving mesh): q and the cache carry stay
     # sharded over "batch" so the fused segment scan never gathers them
     q = shard(q, "batch", None, "heads", "qkv")
     s = cache["k"].shape[1]
-    slot = jnp.where(jnp.asarray(s) > pos, pos, pos % s)   # ring for SWA
-    wslot = slot if active is None else jnp.where(active, slot, s)
-    rows = jnp.arange(b)
-    if "k_s" in cache:
-        k1, ks = Q.quant_store(k[:, 0], axis=-1, dtype=flags.kv_quant)
-        v1, vs = Q.quant_store(v[:, 0], axis=-1, dtype=flags.kv_quant)
-    else:
-        k1, v1 = k[:, 0].astype(cache["k"].dtype), v[:, 0].astype(
-            cache["v"].dtype)
-    kc = cache["k"].at[rows, wslot].set(k1, mode="drop")
-    vc = cache["v"].at[rows, wslot].set(v1, mode="drop")
-    kc = shard(kc, "batch", "cache_seq", "kv_heads", "qkv")
-    vc = shard(vc, "batch", "cache_seq", "kv_heads", "qkv")
-    new_pos = pos + 1 if active is None else pos + active.astype(jnp.int32)
-    new = dict(cache, k=kc, v=vc, pos=new_pos)
-    if "k_s" in cache:
-        new["k_s"] = shard(cache["k_s"].at[rows, wslot].set(ks, mode="drop"),
-                           "batch", "cache_seq", "kv_heads")
-        new["v_s"] = shard(cache["v_s"].at[rows, wslot].set(vs, mode="drop"),
-                           "batch", "cache_seq", "kv_heads")
+    with jax.named_scope("kv_write"):
+        slot = jnp.where(jnp.asarray(s) > pos, pos, pos % s)  # SWA ring
+        wslot = slot if active is None else jnp.where(active, slot, s)
+        rows = jnp.arange(b)
+        if "k_s" in cache:
+            k1, ks = Q.quant_store(k[:, 0], axis=-1, dtype=flags.kv_quant)
+            v1, vs = Q.quant_store(v[:, 0], axis=-1, dtype=flags.kv_quant)
+        else:
+            k1, v1 = k[:, 0].astype(cache["k"].dtype), v[:, 0].astype(
+                cache["v"].dtype)
+        kc = cache["k"].at[rows, wslot].set(k1, mode="drop")
+        vc = cache["v"].at[rows, wslot].set(v1, mode="drop")
+        kc = shard(kc, "batch", "cache_seq", "kv_heads", "qkv")
+        vc = shard(vc, "batch", "cache_seq", "kv_heads", "qkv")
+        new_pos = (pos + 1 if active is None
+                   else pos + active.astype(jnp.int32))
+        new = dict(cache, k=kc, v=vc, pos=new_pos)
+        if "k_s" in cache:
+            new["k_s"] = shard(
+                cache["k_s"].at[rows, wslot].set(ks, mode="drop"),
+                "batch", "cache_seq", "kv_heads")
+            new["v_s"] = shard(
+                cache["v_s"].at[rows, wslot].set(vs, mode="drop"),
+                "batch", "cache_seq", "kv_heads")
     kv_len = jnp.minimum(pos + 1, s).astype(jnp.int32)
     if active is not None:
         kv_len = jnp.where(active, kv_len, 0)
@@ -544,21 +549,24 @@ def _apply_decode(params, cfg: ArchConfig, flags: RunFlags, x, cache,
         out = _dsa_decode(params, cfg, flags, x, q, kc, vc, new, wslot,
                           kv_len)
     else:
-        kc, vc = _kv_views(new, kc, vc)
-        # SWA window semantics: init_cache_attention sizes the ring buffer
-        # at s = min(max_len, decode_window, swa_window) slots, so with SWA
-        # on (s <= window) the buffer can never hold more than one window
-        # of live tokens — the window is enforced STRUCTURALLY and masking
-        # reduces to kv_len validity.  A positional window over *slot*
-        # indices would be wrong after wrap-around (slot order != temporal
-        # order); the explicit mask below is only correct for externally
-        # built caches that are larger than the window and not yet wrapped.
-        # Pinned by tests/test_decode_fastpath.py::test_swa_window_ring_wrap.
-        win = cfg.swa_window or 0
-        out = A.decode_attention(q, kc, vc, kv_len=kv_len,
-                                 window=win if win and s > win else 0)
-    out = shard(out, "batch", None, "heads", "qkv")
-    out = out.reshape(b, 1, -1) @ params["wo"]
+        with jax.named_scope("attend"):
+            kc, vc = _kv_views(new, kc, vc)
+            # SWA window semantics: init_cache_attention sizes the ring
+            # buffer at s = min(max_len, decode_window, swa_window) slots, so
+            # with SWA on (s <= window) the buffer can never hold more than
+            # one window of live tokens — the window is enforced
+            # STRUCTURALLY and masking reduces to kv_len validity.  A
+            # positional window over *slot* indices would be wrong after
+            # wrap-around (slot order != temporal order); the explicit mask
+            # below is only correct for externally built caches that are
+            # larger than the window and not yet wrapped.  Pinned by
+            # tests/test_decode_fastpath.py::test_swa_window_ring_wrap.
+            win = cfg.swa_window or 0
+            out = A.decode_attention(q, kc, vc, kv_len=kv_len,
+                                     window=win if win and s > win else 0)
+    with jax.named_scope("attend"):
+        out = shard(out, "batch", None, "heads", "qkv")
+        out = out.reshape(b, 1, -1) @ params["wo"]
     return out, new, {}
 
 
@@ -576,18 +584,19 @@ def _dsa_decode(params, cfg: ArchConfig, flags: RunFlags, x, q, kc, vc,
     dsa = cfg.dsa
     b, s = kc.shape[0], kc.shape[1]
     rows = jnp.arange(b)
-    q_t, k_t = PRED.predict_qk(params["dsa"], x, None, dsa.quant_bits)
-    if "kt_s" in new:
-        ktq, kts = Q.quant_store(k_t[:, 0], axis=-1)
-        new["kt"] = shard(new["kt"].at[rows, wslot].set(ktq, mode="drop"),
-                          "batch", "cache_seq", "pred_k")
-        new["kt_s"] = shard(
-            new["kt_s"].at[rows, wslot].set(kts, mode="drop"),
-            "batch", "cache_seq")
-    else:
-        new["kt"] = shard(new["kt"].at[rows, wslot].set(
-            k_t[:, 0].astype(new["kt"].dtype), mode="drop"),
-            "batch", "cache_seq", "pred_k")
+    with jax.named_scope("dsa_predict"):
+        q_t, k_t = PRED.predict_qk(params["dsa"], x, None, dsa.quant_bits)
+        if "kt_s" in new:
+            ktq, kts = Q.quant_store(k_t[:, 0], axis=-1)
+            new["kt"] = shard(new["kt"].at[rows, wslot].set(ktq, mode="drop"),
+                              "batch", "cache_seq", "pred_k")
+            new["kt_s"] = shard(
+                new["kt_s"].at[rows, wslot].set(kts, mode="drop"),
+                "batch", "cache_seq")
+        else:
+            new["kt"] = shard(new["kt"].at[rows, wslot].set(
+                k_t[:, 0].astype(new["kt"].dtype), mode="drop"),
+                "batch", "cache_seq", "pred_k")
     k_scale = new.get("k_s")
     v_scale = new.get("v_s")
     keep = M.keep_count(s, dsa.sparsity)
@@ -595,18 +604,22 @@ def _dsa_decode(params, cfg: ArchConfig, flags: RunFlags, x, q, kc, vc,
         # per-request dsa_mode override on a long-context engine: dense
         # decode over the full cache; kt stays maintained (ktb, like the
         # faithful path, is rebuilt at each admission's prefill)
-        kd, vd = _kv_views(new, kc, vc)
-        return A.decode_attention(q, kd, vd, kv_len=kv_len)
+        with jax.named_scope("attend"):
+            kd, vd = _kv_views(new, kc, vc)
+            return A.decode_attention(q, kd, vd, kv_len=kv_len)
     if flags.dsa_mode == "faithful":
         # paper-faithful token granularity: top-k over all S cached scores
-        if "kt_s" in new:
-            s_tilde = _int8_select_scores(q_t, new["kt"], new["kt_s"])[:, 0]
-        else:
-            s_tilde = jnp.einsum("bok,bsk->bs", q_t.astype(jnp.float32),
-                                 new["kt"].astype(jnp.float32))
-        kd, vd = _kv_views(new, kc, vc)
-        return A.dsa_decode_attention(q, kd, vd, s_tilde, keep=keep,
-                                      kv_len=kv_len, local=DECODE_LOCAL)
+        with jax.named_scope("dsa_select"):
+            if "kt_s" in new:
+                s_tilde = _int8_select_scores(q_t, new["kt"],
+                                              new["kt_s"])[:, 0]
+            else:
+                s_tilde = jnp.einsum("bok,bsk->bs", q_t.astype(jnp.float32),
+                                     new["kt"].astype(jnp.float32))
+        with jax.named_scope("attend"):
+            kd, vd = _kv_views(new, kc, vc)
+            return A.dsa_decode_attention(q, kd, vd, s_tilde, keep=keep,
+                                          kv_len=kv_len, local=DECODE_LOCAL)
     # block granularity (decode fast path): maintain running block sums of
     # kt, score S/block_k blocks, select, then gather whole blocks.  The
     # long-context cache never wraps (module docstring), so the slot being
@@ -618,34 +631,41 @@ def _dsa_decode(params, cfg: ArchConfig, flags: RunFlags, x, q, kc, vc,
     if "ktb_s" in new:
         # int8 block sums can't scatter-add across scales: gather the
         # touched block, dequantize, add the new row, requantize, set
-        jc = jnp.minimum(jb, n_kb - 1)
-        old = Q.dequant(new["ktb"][rows, jc], new["ktb_s"][rows, jc])
-        bq_, bs_ = Q.quant_store(old + k_t[:, 0], axis=-1)
-        new["ktb"] = shard(new["ktb"].at[rows, jb].set(bq_, mode="drop"),
-                           "batch", "blocks", "pred_k")
-        new["ktb_s"] = shard(
-            new["ktb_s"].at[rows, jb].set(bs_, mode="drop"),
-            "batch", "blocks")
-        s_blk = _int8_select_scores(q_t, new["ktb"], new["ktb_s"],
-                                    block_k=bkd)[:, 0]
+        with jax.named_scope("dsa_predict"):
+            jc = jnp.minimum(jb, n_kb - 1)
+            old = Q.dequant(new["ktb"][rows, jc], new["ktb_s"][rows, jc])
+            bq_, bs_ = Q.quant_store(old + k_t[:, 0], axis=-1)
+            new["ktb"] = shard(new["ktb"].at[rows, jb].set(bq_, mode="drop"),
+                               "batch", "blocks", "pred_k")
+            new["ktb_s"] = shard(
+                new["ktb_s"].at[rows, jb].set(bs_, mode="drop"),
+                "batch", "blocks")
+        with jax.named_scope("dsa_select"):
+            s_blk = _int8_select_scores(q_t, new["ktb"], new["ktb_s"],
+                                        block_k=bkd)[:, 0]
     else:
-        new["ktb"] = shard(new["ktb"].at[rows, jb].add(
-            k_t[:, 0].astype(new["ktb"].dtype), mode="drop"),
-            "batch", "blocks", "pred_k")
-        s_blk = jnp.einsum("bok,bjk->bj", q_t.astype(jnp.float32),
-                           new["ktb"].astype(jnp.float32)) / bkd
+        with jax.named_scope("dsa_predict"):
+            new["ktb"] = shard(new["ktb"].at[rows, jb].add(
+                k_t[:, 0].astype(new["ktb"].dtype), mode="drop"),
+                "batch", "blocks", "pred_k")
+        with jax.named_scope("dsa_select"):
+            s_blk = jnp.einsum("bok,bjk->bj", q_t.astype(jnp.float32),
+                               new["ktb"].astype(jnp.float32)) / bkd
     nb_keep = min(n_kb, -(-keep // bkd) + -(-DECODE_LOCAL // bkd) + 1)
-    idx, ok = M.decode_block_topk_indices(s_blk, nb_keep, kv_len=kv_len,
-                                          block_k=bkd, local=DECODE_LOCAL)
+    with jax.named_scope("dsa_select"):
+        idx, ok = M.decode_block_topk_indices(s_blk, nb_keep, kv_len=kv_len,
+                                              block_k=bkd,
+                                              local=DECODE_LOCAL)
     if flags.sel_probe:
         new["sel_idx"], new["sel_ok"], new["sel_kv"] = idx, ok, kv_len
-    if flags.dsa_mode == "kernel":
-        from repro.kernels.ops import dsa_decode as dsa_decode_kernel
-        return dsa_decode_kernel(q, kc, vc, idx, ok, kv_len, block_k=bkd,
-                                 k_scale=k_scale, v_scale=v_scale)
-    return A.dsa_decode_block_attention(q, kc, vc, idx, ok, block_k=bkd,
-                                        kv_len=kv_len, k_scale=k_scale,
-                                        v_scale=v_scale)
+    with jax.named_scope("attend"):
+        if flags.dsa_mode == "kernel":
+            from repro.kernels.ops import dsa_decode as dsa_decode_kernel
+            return dsa_decode_kernel(q, kc, vc, idx, ok, kv_len, block_k=bkd,
+                                     k_scale=k_scale, v_scale=v_scale)
+        return A.dsa_decode_block_attention(q, kc, vc, idx, ok, block_k=bkd,
+                                            kv_len=kv_len, k_scale=k_scale,
+                                            v_scale=v_scale)
 
 
 # ---------------------------------------------------------------------------
@@ -681,39 +701,42 @@ def _apply_paged_decode(params, cfg: ArchConfig, flags: RunFlags, x, cache,
     """
     b = x.shape[0]
     pos = _slot_pos(cache, b)                              # (B,)
-    q, k, v = _proj_qkv(params, cfg, x)
-    if use_rope:
-        p = pos[:, None]
-        q = rope(q, p, cfg.rope_theta)
-        k = rope(k, p, cfg.rope_theta)
+    with jax.named_scope("qkv"):
+        q, k, v = _proj_qkv(params, cfg, x)
+        if use_rope:
+            p = pos[:, None]
+            q = rope(q, p, cfg.rope_theta)
+            k = rope(k, p, cfg.rope_theta)
     q = shard(q, "batch", None, "heads", "qkv")
     tbl = cache["page_tbl"]
     n_kb = tbl.shape[1]
     bk = cfg.dsa.block_k if "kt" in cache else PAGE_SIZE
     s = n_kb * bk                                          # logical length
     nrows = cache["k"].shape[0]                            # pool rows
-    wslot = pos if active is None else jnp.where(active, pos, s)
-    rows = jnp.arange(b)
-    pg = tbl[rows, jnp.clip(wslot // bk, 0, n_kb - 1)]
-    okw = (wslot < s) & (pg > 0)
-    flat = jnp.where(okw, pg * bk + wslot % bk, nrows)
-    if "k_s" in cache:
-        k1, ks = Q.quant_store(k[:, 0], axis=-1, dtype=flags.kv_quant)
-        v1, vs = Q.quant_store(v[:, 0], axis=-1, dtype=flags.kv_quant)
-    else:
-        k1, v1 = k[:, 0].astype(cache["k"].dtype), v[:, 0].astype(
-            cache["v"].dtype)
-    kc = cache["k"].at[flat].set(k1, mode="drop")
-    vc = cache["v"].at[flat].set(v1, mode="drop")
-    kc = shard(kc, "pages", "kv_heads", "qkv")
-    vc = shard(vc, "pages", "kv_heads", "qkv")
-    new_pos = pos + 1 if active is None else pos + active.astype(jnp.int32)
-    new = dict(cache, k=kc, v=vc, pos=new_pos)
-    if "k_s" in cache:
-        new["k_s"] = shard(cache["k_s"].at[flat].set(ks, mode="drop"),
-                           "pages", "kv_heads")
-        new["v_s"] = shard(cache["v_s"].at[flat].set(vs, mode="drop"),
-                           "pages", "kv_heads")
+    with jax.named_scope("kv_write"):
+        wslot = pos if active is None else jnp.where(active, pos, s)
+        rows = jnp.arange(b)
+        pg = tbl[rows, jnp.clip(wslot // bk, 0, n_kb - 1)]
+        okw = (wslot < s) & (pg > 0)
+        flat = jnp.where(okw, pg * bk + wslot % bk, nrows)
+        if "k_s" in cache:
+            k1, ks = Q.quant_store(k[:, 0], axis=-1, dtype=flags.kv_quant)
+            v1, vs = Q.quant_store(v[:, 0], axis=-1, dtype=flags.kv_quant)
+        else:
+            k1, v1 = k[:, 0].astype(cache["k"].dtype), v[:, 0].astype(
+                cache["v"].dtype)
+        kc = cache["k"].at[flat].set(k1, mode="drop")
+        vc = cache["v"].at[flat].set(v1, mode="drop")
+        kc = shard(kc, "pages", "kv_heads", "qkv")
+        vc = shard(vc, "pages", "kv_heads", "qkv")
+        new_pos = (pos + 1 if active is None
+                   else pos + active.astype(jnp.int32))
+        new = dict(cache, k=kc, v=vc, pos=new_pos)
+        if "k_s" in cache:
+            new["k_s"] = shard(cache["k_s"].at[flat].set(ks, mode="drop"),
+                               "pages", "kv_heads")
+            new["v_s"] = shard(cache["v_s"].at[flat].set(vs, mode="drop"),
+                               "pages", "kv_heads")
     kv_len = jnp.minimum(pos + 1, s).astype(jnp.int32)
     if active is not None:
         kv_len = jnp.where(active, kv_len, 0)
@@ -722,14 +745,16 @@ def _apply_paged_decode(params, cfg: ArchConfig, flags: RunFlags, x, cache,
         out = _dsa_paged_decode(params, cfg, flags, x, q, kc, vc, new,
                                 flat, okw, pg, kv_len, view, bk)
     else:
-        if "k_s" in new:
-            kd = Q.dequant(kc[view], new["k_s"][view])
-            vd = Q.dequant(vc[view], new["v_s"][view])
-        else:
-            kd, vd = kc[view], vc[view]
-        out = A.decode_attention(q, kd, vd, kv_len=kv_len)
-    out = shard(out, "batch", None, "heads", "qkv")
-    out = out.reshape(b, 1, -1) @ params["wo"]
+        with jax.named_scope("attend"):
+            if "k_s" in new:
+                kd = Q.dequant(kc[view], new["k_s"][view])
+                vd = Q.dequant(vc[view], new["v_s"][view])
+            else:
+                kd, vd = kc[view], vc[view]
+            out = A.decode_attention(q, kd, vd, kv_len=kv_len)
+    with jax.named_scope("attend"):
+        out = shard(out, "batch", None, "heads", "qkv")
+        out = out.reshape(b, 1, -1) @ params["wo"]
     return out, new, {}
 
 
@@ -745,17 +770,18 @@ def _dsa_paged_decode(params, cfg: ArchConfig, flags: RunFlags, x, q, kc,
     """
     dsa = cfg.dsa
     s = view.shape[1]
-    q_t, k_t = PRED.predict_qk(params["dsa"], x, None, dsa.quant_bits)
-    if "kt_s" in new:
-        ktq, kts = Q.quant_store(k_t[:, 0], axis=-1)
-        ktc = new["kt"].at[flat].set(ktq, mode="drop")
-        kts_c = new["kt_s"].at[flat].set(kts, mode="drop")
-        new["kt"] = shard(ktc, "pages", "pred_k")
-        new["kt_s"] = shard(kts_c, "pages")
-    else:
-        ktc = new["kt"].at[flat].set(k_t[:, 0].astype(new["kt"].dtype),
-                                     mode="drop")
-        new["kt"] = shard(ktc, "pages", "pred_k")
+    with jax.named_scope("dsa_predict"):
+        q_t, k_t = PRED.predict_qk(params["dsa"], x, None, dsa.quant_bits)
+        if "kt_s" in new:
+            ktq, kts = Q.quant_store(k_t[:, 0], axis=-1)
+            ktc = new["kt"].at[flat].set(ktq, mode="drop")
+            kts_c = new["kt_s"].at[flat].set(kts, mode="drop")
+            new["kt"] = shard(ktc, "pages", "pred_k")
+            new["kt_s"] = shard(kts_c, "pages")
+        else:
+            ktc = new["kt"].at[flat].set(k_t[:, 0].astype(new["kt"].dtype),
+                                         mode="drop")
+            new["kt"] = shard(ktc, "pages", "pred_k")
     k_scale = new.get("k_s")
     v_scale = new.get("v_s")
 
@@ -767,58 +793,68 @@ def _dsa_paged_decode(params, cfg: ArchConfig, flags: RunFlags, x, q, kc,
 
     keep = M.keep_count(s, dsa.sparsity)
     if flags.dsa_mode == "off":
-        kd, vd = kv_view()
-        return A.decode_attention(q, kd, vd, kv_len=kv_len)
+        with jax.named_scope("attend"):
+            kd, vd = kv_view()
+            return A.decode_attention(q, kd, vd, kv_len=kv_len)
     if flags.dsa_mode == "faithful":
-        if "kt_s" in new:
-            s_tilde = _int8_select_scores(q_t, ktc[view],
-                                          kts_c[view])[:, 0]
-        else:
-            s_tilde = jnp.einsum("bok,bsk->bs", q_t.astype(jnp.float32),
-                                 ktc[view].astype(jnp.float32))
-        kd, vd = kv_view()
-        return A.dsa_decode_attention(q, kd, vd, s_tilde,
-                                      keep=keep, kv_len=kv_len,
-                                      local=DECODE_LOCAL)
+        with jax.named_scope("dsa_select"):
+            if "kt_s" in new:
+                s_tilde = _int8_select_scores(q_t, ktc[view],
+                                              kts_c[view])[:, 0]
+            else:
+                s_tilde = jnp.einsum("bok,bsk->bs", q_t.astype(jnp.float32),
+                                     ktc[view].astype(jnp.float32))
+        with jax.named_scope("attend"):
+            kd, vd = kv_view()
+            return A.dsa_decode_attention(q, kd, vd, s_tilde,
+                                          keep=keep, kv_len=kv_len,
+                                          local=DECODE_LOCAL)
     npages = new["ktb"].shape[0]
     tbl = new["page_tbl"]
     n_kb = tbl.shape[1]
     if "ktb_s" in new:
         # per-page int8 block sums: dequant the touched page's row, add,
         # requant, set (frozen rows gather the zero page and drop the set)
-        src = jnp.where(okw, pg, 0)
-        old = Q.dequant(new["ktb"][src], new["ktb_s"][src])
-        bq_, bs_ = Q.quant_store(old + k_t[:, 0], axis=-1)
-        tgt = jnp.where(okw, pg, npages)
-        ktb = new["ktb"].at[tgt].set(bq_, mode="drop")
-        ktb_s = new["ktb_s"].at[tgt].set(bs_, mode="drop")
-        new["ktb"] = shard(ktb, "pages", "pred_k")
-        new["ktb_s"] = shard(ktb_s, "pages")
-        s_blk = _int8_select_scores(q_t, ktb[tbl], ktb_s[tbl],
-                                    block_k=bk)[:, 0]
+        with jax.named_scope("dsa_predict"):
+            src = jnp.where(okw, pg, 0)
+            old = Q.dequant(new["ktb"][src], new["ktb_s"][src])
+            bq_, bs_ = Q.quant_store(old + k_t[:, 0], axis=-1)
+            tgt = jnp.where(okw, pg, npages)
+            ktb = new["ktb"].at[tgt].set(bq_, mode="drop")
+            ktb_s = new["ktb_s"].at[tgt].set(bs_, mode="drop")
+            new["ktb"] = shard(ktb, "pages", "pred_k")
+            new["ktb_s"] = shard(ktb_s, "pages")
+        with jax.named_scope("dsa_select"):
+            s_blk = _int8_select_scores(q_t, ktb[tbl], ktb_s[tbl],
+                                        block_k=bk)[:, 0]
     else:
-        ktb = new["ktb"].at[jnp.where(okw, pg, npages)].add(
-            k_t[:, 0].astype(new["ktb"].dtype), mode="drop")
-        new["ktb"] = shard(ktb, "pages", "pred_k")
-        s_blk = jnp.einsum("bok,bjk->bj", q_t.astype(jnp.float32),
-                           ktb[tbl].astype(jnp.float32)) / bk
+        with jax.named_scope("dsa_predict"):
+            ktb = new["ktb"].at[jnp.where(okw, pg, npages)].add(
+                k_t[:, 0].astype(new["ktb"].dtype), mode="drop")
+            new["ktb"] = shard(ktb, "pages", "pred_k")
+        with jax.named_scope("dsa_select"):
+            s_blk = jnp.einsum("bok,bjk->bj", q_t.astype(jnp.float32),
+                               ktb[tbl].astype(jnp.float32)) / bk
     nb_keep = min(n_kb, -(-keep // bk) + -(-DECODE_LOCAL // bk) + 1)
-    idx, ok = M.decode_block_topk_indices(s_blk, nb_keep, kv_len=kv_len,
-                                          block_k=bk, local=DECODE_LOCAL)
+    with jax.named_scope("dsa_select"):
+        idx, ok = M.decode_block_topk_indices(s_blk, nb_keep, kv_len=kv_len,
+                                              block_k=bk,
+                                              local=DECODE_LOCAL)
+        pidx = jnp.take_along_axis(tbl, idx, axis=1)      # physical pages
     if flags.sel_probe:
         # logical block indices (pre page translation): comparable across
         # steps even when the physical mapping changes
         new["sel_idx"], new["sel_ok"], new["sel_kv"] = idx, ok, kv_len
-    pidx = jnp.take_along_axis(tbl, idx, axis=1)          # physical pages
-    if flags.dsa_mode == "kernel":
-        from repro.kernels.ops import dsa_decode_paged as dsa_paged_kernel
-        return dsa_paged_kernel(q, kc, vc, idx, pidx, ok, kv_len,
-                                block_k=bk, k_scale=k_scale,
-                                v_scale=v_scale)
-    return A.dsa_decode_paged_block_attention(q, kc, vc, idx, pidx, ok,
-                                              block_k=bk, kv_len=kv_len,
-                                              k_scale=k_scale,
-                                              v_scale=v_scale)
+    with jax.named_scope("attend"):
+        if flags.dsa_mode == "kernel":
+            from repro.kernels.ops import dsa_decode_paged as dsa_paged_kernel
+            return dsa_paged_kernel(q, kc, vc, idx, pidx, ok, kv_len,
+                                    block_k=bk, k_scale=k_scale,
+                                    v_scale=v_scale)
+        return A.dsa_decode_paged_block_attention(q, kc, vc, idx, pidx, ok,
+                                                  block_k=bk, kv_len=kv_len,
+                                                  k_scale=k_scale,
+                                                  v_scale=v_scale)
 
 
 # ---------------------------------------------------------------------------
@@ -854,12 +890,13 @@ def _apply_chunk(params, cfg: ArchConfig, flags: RunFlags, x, cache,
     b, c = x.shape[:2]
     sel = cache["k"].shape[1] if sel_len is None else sel_len
     pos = _slot_pos(cache, b)                              # (B,)
-    q, k, v = _proj_qkv(params, cfg, x)
     offs = jnp.arange(c)
     p = pos[:, None] + offs[None, :]                       # (B, C) global
-    if use_rope:
-        q = rope(q, p, cfg.rope_theta)
-        k = rope(k, p, cfg.rope_theta)
+    with jax.named_scope("qkv"):
+        q, k, v = _proj_qkv(params, cfg, x)
+        if use_rope:
+            q = rope(q, p, cfg.rope_theta)
+            k = rope(k, p, cfg.rope_theta)
     s = cache["k"].shape[1]
     live = offs[None, :] < chunk_len[:, None]              # (B, C)
     if active is not None:
@@ -870,33 +907,34 @@ def _apply_chunk(params, cfg: ArchConfig, flags: RunFlags, x, cache,
     wslot = p if active is None else jnp.where(active[:, None], p, s)
     rows = jnp.arange(b)[:, None]
     q = shard(q, "batch", None, "heads", "qkv")
-    if "k_s" in cache:
-        # pad rows quantize to (0, scale 0.0): dequant reproduces the exact
-        # zero rows truncate_cache leaves
-        kq, ks = Q.quant_store(jnp.where(live[..., None, None], k, 0),
-                               axis=-1, dtype=flags.kv_quant)
-        vq, vs = Q.quant_store(jnp.where(live[..., None, None], v, 0),
-                               axis=-1, dtype=flags.kv_quant)
-        kc = cache["k"].at[rows, wslot].set(kq, mode="drop")
-        vc = cache["v"].at[rows, wslot].set(vq, mode="drop")
-    else:
-        kc = cache["k"].at[rows, wslot].set(
-            jnp.where(live[..., None, None], k, 0).astype(cache["k"].dtype),
-            mode="drop")
-        vc = cache["v"].at[rows, wslot].set(
-            jnp.where(live[..., None, None], v, 0).astype(cache["v"].dtype),
-            mode="drop")
-    kc = shard(kc, "batch", "cache_seq", "kv_heads", "qkv")
-    vc = shard(vc, "batch", "cache_seq", "kv_heads", "qkv")
-    adv = chunk_len if active is None else jnp.where(active, chunk_len, 0)
-    new = dict(cache, k=kc, v=vc, pos=pos + adv)
-    if "k_s" in cache:
-        new["k_s"] = shard(
-            cache["k_s"].at[rows, wslot].set(ks, mode="drop"),
-            "batch", "cache_seq", "kv_heads")
-        new["v_s"] = shard(
-            cache["v_s"].at[rows, wslot].set(vs, mode="drop"),
-            "batch", "cache_seq", "kv_heads")
+    with jax.named_scope("kv_write"):
+        if "k_s" in cache:
+            # pad rows quantize to (0, scale 0.0): dequant reproduces the exact
+            # zero rows truncate_cache leaves
+            kq, ks = Q.quant_store(jnp.where(live[..., None, None], k, 0),
+                                   axis=-1, dtype=flags.kv_quant)
+            vq, vs = Q.quant_store(jnp.where(live[..., None, None], v, 0),
+                                   axis=-1, dtype=flags.kv_quant)
+            kc = cache["k"].at[rows, wslot].set(kq, mode="drop")
+            vc = cache["v"].at[rows, wslot].set(vq, mode="drop")
+        else:
+            kc = cache["k"].at[rows, wslot].set(
+                jnp.where(live[..., None, None], k, 0).astype(
+                    cache["k"].dtype), mode="drop")
+            vc = cache["v"].at[rows, wslot].set(
+                jnp.where(live[..., None, None], v, 0).astype(
+                    cache["v"].dtype), mode="drop")
+        kc = shard(kc, "batch", "cache_seq", "kv_heads", "qkv")
+        vc = shard(vc, "batch", "cache_seq", "kv_heads", "qkv")
+        adv = chunk_len if active is None else jnp.where(active, chunk_len, 0)
+        new = dict(cache, k=kc, v=vc, pos=pos + adv)
+        if "k_s" in cache:
+            new["k_s"] = shard(
+                cache["k_s"].at[rows, wslot].set(ks, mode="drop"),
+                "batch", "cache_seq", "kv_heads")
+            new["v_s"] = shard(
+                cache["v_s"].at[rows, wslot].set(vs, mode="drop"),
+                "batch", "cache_seq", "kv_heads")
     kv_len = (pos + adv).astype(jnp.int32)
 
     def sel_kv():
@@ -916,14 +954,18 @@ def _apply_chunk(params, cfg: ArchConfig, flags: RunFlags, x, cache,
                 k_scale=new["k_s"][:, :sel] if "k_s" in new else None,
                 v_scale=new["v_s"][:, :sel] if "v_s" in new else None)
         else:
-            out = A.chunk_attention(q, *sel_kv(), p)
+            with jax.named_scope("attend"):
+                out = A.chunk_attention(q, *sel_kv(), p)
     else:
-        out = A.chunk_attention(q, *sel_kv(), p)
-    out = shard(out, "batch", None, "heads", "qkv")
-    out = out.reshape(b, c, -1) @ params["wo"]
+        with jax.named_scope("attend"):
+            out = A.chunk_attention(q, *sel_kv(), p)
+    with jax.named_scope("attend"):
+        out = shard(out, "batch", None, "heads", "qkv")
+        out = out.reshape(b, c, -1) @ params["wo"]
     return out, new, {}
 
 
+@jax.named_scope("dsa_predict")
 def _chunk_fill_pred(params, cfg: ArchConfig, x, new, wslot, live, pos,
                      active):
     """Extend the predicted-key cache ``kt`` and its block-pooled twin
@@ -1006,39 +1048,43 @@ def _dsa_chunk_attend(cfg: ArchConfig, flags: RunFlags, q, kc, vc, q_t,
     s = kc.shape[1]
     if flags.dsa_mode == "faithful" or s % dsa.block_q or s % dsa.block_k:
         # token granularity — the whole-prompt path for this geometry
-        if kt_sel_s is not None:
-            s_t = _int8_select_scores(q_t, kt_sel, kt_sel_s)
-        else:
-            s_t = jnp.einsum("bqk,bsk->bqs", q_t, kt_sel)
-        valid = jnp.arange(s)[None, None, :] <= p[:, :, None]
-        keep = M.keep_count(s, dsa.sparsity)
-        mask = M.row_topk_mask(s_t, keep, valid)
-        if k_scale is not None:
-            kc, vc = Q.dequant(kc, k_scale), Q.dequant(vc, v_scale)
-        return A.chunk_attention(q, kc, vc, p, token_mask=mask)
+        with jax.named_scope("dsa_select"):
+            if kt_sel_s is not None:
+                s_t = _int8_select_scores(q_t, kt_sel, kt_sel_s)
+            else:
+                s_t = jnp.einsum("bqk,bsk->bqs", q_t, kt_sel)
+            valid = jnp.arange(s)[None, None, :] <= p[:, :, None]
+            keep = M.keep_count(s, dsa.sparsity)
+            mask = M.row_topk_mask(s_t, keep, valid)
+        with jax.named_scope("attend"):
+            if k_scale is not None:
+                kc, vc = Q.dequant(kc, k_scale), Q.dequant(vc, v_scale)
+            return A.chunk_attention(q, kc, vc, p, token_mask=mask)
     bq, bkd = dsa.block_q, dsa.block_k
     assert c % bq == 0, (c, bq)
     n_kb = s // bkd
-    q_blk = q_t.reshape(b, c // bq, bq, -1).mean(axis=2)
-    if kt_sel_s is not None:
-        sc = _int8_select_scores(q_blk, kt_sel, kt_sel_s)  # (B, nQb, S)
-    else:
-        sc = jnp.einsum("bqk,bsk->bqs", q_blk, kt_sel)     # (B, nQb, S)
-    bs = sc.reshape(b, c // bq, n_kb, bkd).max(axis=-1)
-    nb_keep = min(n_kb, max(dsa.min_blocks + dsa.local_blocks,
-                            M.keep_count(n_kb, dsa.sparsity)))
-    idx, ok = M.chunk_block_topk_indices(
-        bs, nb_keep, q_block_offset=pos // bq,
-        local_blocks=dsa.local_blocks, sort=dsa.sort_indices)
-    if flags.dsa_mode == "kernel":
-        from repro.kernels.ops import dsa_chunk_prefill as chunk_kernel
-        return chunk_kernel(q, kc, vc, idx, ok, pos, kv_len,
-                            block_q=bq, block_k=bkd, k_scale=k_scale,
-                            v_scale=v_scale)
-    return A.dsa_chunk_block_attention(q, kc, vc, idx, ok, block_q=bq,
-                                       block_k=bkd, q_offset=pos,
-                                       kv_len=kv_len, k_scale=k_scale,
-                                       v_scale=v_scale)
+    with jax.named_scope("dsa_select"):
+        q_blk = q_t.reshape(b, c // bq, bq, -1).mean(axis=2)
+        if kt_sel_s is not None:
+            sc = _int8_select_scores(q_blk, kt_sel, kt_sel_s)  # (B,nQb,S)
+        else:
+            sc = jnp.einsum("bqk,bsk->bqs", q_blk, kt_sel)     # (B,nQb,S)
+        bs = sc.reshape(b, c // bq, n_kb, bkd).max(axis=-1)
+        nb_keep = min(n_kb, max(dsa.min_blocks + dsa.local_blocks,
+                                M.keep_count(n_kb, dsa.sparsity)))
+        idx, ok = M.chunk_block_topk_indices(
+            bs, nb_keep, q_block_offset=pos // bq,
+            local_blocks=dsa.local_blocks, sort=dsa.sort_indices)
+    with jax.named_scope("attend"):
+        if flags.dsa_mode == "kernel":
+            from repro.kernels.ops import dsa_chunk_prefill as chunk_kernel
+            return chunk_kernel(q, kc, vc, idx, ok, pos, kv_len,
+                                block_q=bq, block_k=bkd, k_scale=k_scale,
+                                v_scale=v_scale)
+        return A.dsa_chunk_block_attention(q, kc, vc, idx, ok, block_q=bq,
+                                           block_k=bkd, q_offset=pos,
+                                           kv_len=kv_len, k_scale=k_scale,
+                                           v_scale=v_scale)
 
 
 # ---------------------------------------------------------------------------
@@ -1071,49 +1117,54 @@ def _apply_verify(params, cfg: ArchConfig, flags: RunFlags, x, cache,
     assert not cfg.swa_window, "speculative verify needs a non-wrapping cache"
     b, c = x.shape[:2]
     pos = _slot_pos(cache, b)                              # (B,)
-    q, k, v = _proj_qkv(params, cfg, x)
     offs = jnp.arange(c)
     p = pos[:, None] + offs[None, :]                       # (B, C) global
-    if use_rope:
-        q = rope(q, p, cfg.rope_theta)
-        k = rope(k, p, cfg.rope_theta)
+    with jax.named_scope("qkv"):
+        q, k, v = _proj_qkv(params, cfg, x)
+        if use_rope:
+            q = rope(q, p, cfg.rope_theta)
+            k = rope(k, p, cfg.rope_theta)
     s = cache["k"].shape[1]
     wslot = p if active is None else jnp.where(active[:, None], p, s)
     rows = jnp.arange(b)[:, None]
     q = shard(q, "batch", None, "heads", "qkv")
-    if "k_s" in cache:
-        k1, ks = Q.quant_store(k, axis=-1, dtype=flags.kv_quant)
-        v1, vs = Q.quant_store(v, axis=-1, dtype=flags.kv_quant)
-    else:
-        k1, v1 = k.astype(cache["k"].dtype), v.astype(cache["v"].dtype)
-    kc = cache["k"].at[rows, wslot].set(k1, mode="drop")
-    vc = cache["v"].at[rows, wslot].set(v1, mode="drop")
-    kc = shard(kc, "batch", "cache_seq", "kv_heads", "qkv")
-    vc = shard(vc, "batch", "cache_seq", "kv_heads", "qkv")
-    adv = chunk_len if active is None else jnp.where(active, chunk_len, 0)
-    new = dict(cache, k=kc, v=vc, pos=pos + adv)
-    if "k_s" in cache:
-        new["k_s"] = shard(cache["k_s"].at[rows, wslot].set(ks, mode="drop"),
-                           "batch", "cache_seq", "kv_heads")
-        new["v_s"] = shard(cache["v_s"].at[rows, wslot].set(vs, mode="drop"),
-                           "batch", "cache_seq", "kv_heads")
+    with jax.named_scope("kv_write"):
+        if "k_s" in cache:
+            k1, ks = Q.quant_store(k, axis=-1, dtype=flags.kv_quant)
+            v1, vs = Q.quant_store(v, axis=-1, dtype=flags.kv_quant)
+        else:
+            k1, v1 = k.astype(cache["k"].dtype), v.astype(cache["v"].dtype)
+        kc = cache["k"].at[rows, wslot].set(k1, mode="drop")
+        vc = cache["v"].at[rows, wslot].set(v1, mode="drop")
+        kc = shard(kc, "batch", "cache_seq", "kv_heads", "qkv")
+        vc = shard(vc, "batch", "cache_seq", "kv_heads", "qkv")
+        adv = chunk_len if active is None else jnp.where(active, chunk_len, 0)
+        new = dict(cache, k=kc, v=vc, pos=pos + adv)
+        if "k_s" in cache:
+            new["k_s"] = shard(
+                cache["k_s"].at[rows, wslot].set(ks, mode="drop"),
+                "batch", "cache_seq", "kv_heads")
+            new["v_s"] = shard(
+                cache["v_s"].at[rows, wslot].set(vs, mode="drop"),
+                "batch", "cache_seq", "kv_heads")
     kv_row = (p + 1).astype(jnp.int32)                     # (B, C) per row
     if active is not None:
         kv_row = jnp.where(active[:, None], kv_row, 0)
     if "kt" in cache:
-        q_t, k_t = PRED.predict_qk(params["dsa"], x, None, cfg.dsa.quant_bits)
-        if "kt_s" in cache:
-            ktq, kts = Q.quant_store(k_t, axis=-1)
-            new["kt"] = shard(new["kt"].at[rows, wslot].set(ktq,
-                                                            mode="drop"),
-                              "batch", "cache_seq", "pred_k")
-            new["kt_s"] = shard(
-                new["kt_s"].at[rows, wslot].set(kts, mode="drop"),
-                "batch", "cache_seq")
-        else:
-            new["kt"] = shard(new["kt"].at[rows, wslot].set(
-                k_t.astype(new["kt"].dtype), mode="drop"),
-                "batch", "cache_seq", "pred_k")
+        with jax.named_scope("dsa_predict"):
+            q_t, k_t = PRED.predict_qk(params["dsa"], x, None,
+                                       cfg.dsa.quant_bits)
+            if "kt_s" in cache:
+                ktq, kts = Q.quant_store(k_t, axis=-1)
+                new["kt"] = shard(new["kt"].at[rows, wslot].set(
+                    ktq, mode="drop"), "batch", "cache_seq", "pred_k")
+                new["kt_s"] = shard(
+                    new["kt_s"].at[rows, wslot].set(kts, mode="drop"),
+                    "batch", "cache_seq")
+            else:
+                new["kt"] = shard(new["kt"].at[rows, wslot].set(
+                    k_t.astype(new["kt"].dtype), mode="drop"),
+                    "batch", "cache_seq", "pred_k")
         if dsa_active(cfg, flags):
             out = _dsa_verify_attend(cfg, flags, q, kc, vc, q_t, new["kt"],
                                      new["ktb"], p, kv_row,
@@ -1124,11 +1175,14 @@ def _apply_verify(params, cfg: ArchConfig, flags: RunFlags, x, cache,
         else:
             # dsa_mode "off" on a long-context cache: dense decode over the
             # full buffer (kt maintained, like _dsa_decode's off path)
-            out = A.chunk_attention(q, *_kv_views(new, kc, vc), p)
+            with jax.named_scope("attend"):
+                out = A.chunk_attention(q, *_kv_views(new, kc, vc), p)
     else:
-        out = A.chunk_attention(q, *_kv_views(new, kc, vc), p)
-    out = shard(out, "batch", None, "heads", "qkv")
-    out = out.reshape(b, c, -1) @ params["wo"]
+        with jax.named_scope("attend"):
+            out = A.chunk_attention(q, *_kv_views(new, kc, vc), p)
+    with jax.named_scope("attend"):
+        out = shard(out, "batch", None, "heads", "qkv")
+        out = out.reshape(b, c, -1) @ params["wo"]
     return out, new, {}
 
 
@@ -1150,26 +1204,40 @@ def _dsa_verify_attend(cfg: ArchConfig, flags: RunFlags, q, kc, vc, q_t,
     s = kc.shape[1]
     keep = M.keep_count(s, dsa.sparsity)
     if flags.dsa_mode == "faithful":
-        if kt_s is not None:
-            s_tilde = _int8_select_scores(q_t, kt_full, kt_s)
-        else:
-            s_tilde = jnp.einsum("bck,bsk->bcs", q_t.astype(jnp.float32),
-                                 kt_full.astype(jnp.float32))
-        if k_scale is not None:
-            kc = Q.dequant(kc, k_scale)
-            vc = Q.dequant(vc, v_scale)
-        return A.dsa_verify_attention(q, kc, vc, s_tilde, keep=keep,
-                                      kv_len=kv_row, local=DECODE_LOCAL)
+        with jax.named_scope("dsa_select"):
+            if kt_s is not None:
+                s_tilde = _int8_select_scores(q_t, kt_full, kt_s)
+            else:
+                s_tilde = jnp.einsum("bck,bsk->bcs",
+                                     q_t.astype(jnp.float32),
+                                     kt_full.astype(jnp.float32))
+        with jax.named_scope("attend"):
+            if k_scale is not None:
+                kc = Q.dequant(kc, k_scale)
+                vc = Q.dequant(vc, v_scale)
+            return A.dsa_verify_attention(q, kc, vc, s_tilde, keep=keep,
+                                          kv_len=kv_row, local=DECODE_LOCAL)
     bkd = dsa.block_k
     n_kb = ktb.shape[1]
-    if ktb_s is not None:
-        s_blk = _int8_select_scores(q_t, ktb, ktb_s, block_k=bkd)
-    else:
-        s_blk = jnp.einsum("bck,bjk->bcj", q_t.astype(jnp.float32),
-                           ktb.astype(jnp.float32)) / bkd
-    nb_keep = min(n_kb, -(-keep // bkd) + -(-DECODE_LOCAL // bkd) + 1)
-    idx, ok = M.verify_block_topk_indices(s_blk, nb_keep, kv_len=kv_row,
-                                          block_k=bkd, local=DECODE_LOCAL)
+    with jax.named_scope("dsa_select"):
+        if ktb_s is not None:
+            s_blk = _int8_select_scores(q_t, ktb, ktb_s, block_k=bkd)
+        else:
+            s_blk = jnp.einsum("bck,bjk->bcj", q_t.astype(jnp.float32),
+                               ktb.astype(jnp.float32)) / bkd
+        nb_keep = min(n_kb, -(-keep // bkd) + -(-DECODE_LOCAL // bkd) + 1)
+        idx, ok = M.verify_block_topk_indices(s_blk, nb_keep, kv_len=kv_row,
+                                              block_k=bkd,
+                                              local=DECODE_LOCAL)
+    return _verify_attend(flags, q, kc, vc, idx, ok, kv_row, bkd, c,
+                          k_scale, v_scale)
+
+
+@jax.named_scope("attend")
+def _verify_attend(flags: RunFlags, q, kc, vc, idx, ok, kv_row, bkd: int,
+                   c: int, k_scale, v_scale):
+    """Per-row gather attention over the selected blocks of a verify
+    chunk: the fused kernel once per row, or the XLA twin."""
     if flags.dsa_mode == "kernel":
         from repro.kernels.ops import dsa_decode as dsa_decode_kernel
         # one fused-kernel call per row INSIDE the single verify dispatch:

@@ -175,7 +175,8 @@ def apply_subblock(params, cfg: ArchConfig, flags: RunFlags, d: SubBlockDef,
     """
     aux: Dict[str, jax.Array] = {}
     new_cache = dict(cache) if cache is not None else None
-    h = rms_norm(x, params["norm1"].astype(x.dtype), cfg.norm_eps)
+    with jax.named_scope("qkv"):
+        h = rms_norm(x, params["norm1"].astype(x.dtype), cfg.norm_eps)
     decode = flags.mode == "decode"
     if d.kind == "attn":
         y, c, a = apply_attention(params["attn"], cfg, flags, h,
@@ -200,7 +201,8 @@ def apply_subblock(params, cfg: ArchConfig, flags: RunFlags, d: SubBlockDef,
                               decode=decode)
     if new_cache is not None and c is not None:
         new_cache["attn"] = c
-    x = x + y
+    with jax.named_scope("attend"):
+        x = x + y
     if d.cross and enc is not None or (d.cross and decode):
         h = rms_norm(x, params["xnorm"].astype(x.dtype), cfg.norm_eps)
         y, cx, _ = apply_attention(
@@ -210,23 +212,24 @@ def apply_subblock(params, cfg: ArchConfig, flags: RunFlags, d: SubBlockDef,
         x = x + jnp.tanh(params["xgate"].astype(x.dtype)) * y
         if new_cache is not None and cx is not None:
             new_cache["xattn"] = cx
-    h = rms_norm(x, params["norm2"].astype(x.dtype), cfg.norm_eps)
-    if d.kind == "rwkv":
-        prev = None if cache is None else cache["attn"].get("ffn_prev")
-        y = ssm.apply_rwkv_ffn(params["mlp"], cfg, h, prev)
-        if new_cache is not None:
-            new_cache["attn"]["ffn_prev"] = h[:, -1]
-    elif d.moe:
-        # flags.moe_dense (Engine(moe_prefill="dense")): prefill routes the
-        # decode-dense expert path too, so whole-prompt prefill and chunk
-        # steps are token-exact and MoE archs can chunk-admit
-        y, a = apply_moe(params["mlp"], cfg, h,
-                         decode=decode or flags.moe_dense)
-        for k, v in a.items():
-            aux[k] = aux.get(k, 0.0) + v
-    else:
-        y = apply_mlp(params["mlp"], h)
-    x = x + y
+    with jax.named_scope("mlp"):
+        h = rms_norm(x, params["norm2"].astype(x.dtype), cfg.norm_eps)
+        if d.kind == "rwkv":
+            prev = None if cache is None else cache["attn"].get("ffn_prev")
+            y = ssm.apply_rwkv_ffn(params["mlp"], cfg, h, prev)
+            if new_cache is not None:
+                new_cache["attn"]["ffn_prev"] = h[:, -1]
+        elif d.moe:
+            # flags.moe_dense (Engine(moe_prefill="dense")): prefill routes
+            # the decode-dense expert path too, so whole-prompt prefill and
+            # chunk steps are token-exact and MoE archs can chunk-admit
+            y, a = apply_moe(params["mlp"], cfg, h,
+                             decode=decode or flags.moe_dense)
+            for k, v in a.items():
+                aux[k] = aux.get(k, 0.0) + v
+        else:
+            y = apply_mlp(params["mlp"], h)
+        x = x + y
     x = shard(x, "batch", "seq_sp", "embed_act")
     return x, new_cache, aux
 

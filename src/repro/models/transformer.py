@@ -263,7 +263,8 @@ def _loop_groups_unstacked(gparams, cfg: ArchConfig, flags: RunFlags, defs,
     aux = {k: jnp.zeros((), jnp.float32) for k in AUX_KEYS}
     new_caches = []
     for i, c in enumerate(caches):
-        p = jax.tree.map(lambda a, i=i: a[i], gparams)
+        with jax.named_scope("weights"):
+            p = jax.tree.map(lambda a, i=i: a[i], gparams)
         x, nc, a = B.apply_group(p, cfg, flags, defs, x, cache=c, enc=enc,
                                  active=active, chunk_len=chunk_len,
                                  sel_len=sel_len)
@@ -325,14 +326,16 @@ def forward(params, cfg: ArchConfig, flags: RunFlags,
         for k in AUX_KEYS:
             if k in extra:
                 aux[k] = aux[k] + extra[k]
-    x = rms_norm(x, params["final_norm"].astype(x.dtype), cfg.norm_eps)
-    head = (params["embed"].T if cfg.tie_embeddings
-            else params["lm_head"]).astype(x.dtype)
-    logits = x @ head
-    # "vocab_act", not "vocab": training shards logits over "model", but
-    # the TP serving rules replicate them here (all-gather of columns each
-    # computed whole) so sampling sees a replicated operand, as unsharded
-    logits = shard(logits, "batch", None, "vocab_act")
+    with jax.named_scope("logits_sample"):
+        x = rms_norm(x, params["final_norm"].astype(x.dtype), cfg.norm_eps)
+        head = (params["embed"].T if cfg.tie_embeddings
+                else params["lm_head"]).astype(x.dtype)
+        logits = x @ head
+        # "vocab_act", not "vocab": training shards logits over "model", but
+        # the TP serving rules replicate them here (all-gather of columns
+        # each computed whole) so sampling sees a replicated operand, as
+        # unsharded
+        logits = shard(logits, "batch", None, "vocab_act")
     new_caches = None
     if caches is not None:
         new_caches = dict(caches, groups=new_gc)

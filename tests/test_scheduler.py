@@ -287,6 +287,29 @@ def test_ttft_reported_before_finish(dense):
         assert r.ttft_s <= r.latency_s
 
 
+@pytest.mark.parametrize("chunked", [True, False],
+                         ids=["chunked", "blocking"])
+def test_admission_stamps_order(dense, chunked):
+    """``admit_s`` is when the request's admission group started, on both
+    admission paths: arrival <= admit <= first token <= finish for every
+    request; one queued behind a full slot set was admitted only after a
+    slot freed (so after its arrival); and a multi-chunk prompt's
+    admission starts before its first token."""
+    cfg, params, _, _ = dense
+    ce = ContinuousEngine(cfg, params, slots=2, max_len=MAX_LEN, seg_len=4,
+                          chunk_tokens=16, chunked_prefill=chunked)
+    shapes = [(40, 6), (36, 5), (40, 4), (20, 1), (33, 6)]
+    results = ce.serve(_mk_requests(cfg.vocab, shapes, seed=5))
+    assert [r.status for r in results] == ["ok"] * len(shapes)
+    for r in results:
+        assert r.arrival_s <= r.admit_s <= r.first_token_s <= r.finish_s
+        assert r.admit_s < r.first_token_s          # >= 2 chunks each
+    first_free = min(r.finish_s for r in results[:2])
+    for r in results[2:]:                 # queued behind both slots
+        assert r.admit_s > r.arrival_s
+        assert r.admit_s >= first_free
+
+
 def test_moe_dense_prefill_enables_chunked_admission(rng):
     """moe_prefill="dense": whole-prompt prefill routes the decode-dense
     expert path, so MoE archs chunk-admit (can_chunk_prefill flips) and

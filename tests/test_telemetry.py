@@ -2,8 +2,12 @@
 
  - ``telemetry=None`` (the ServingConfig default) is BITWISE-INERT:
    tokens from a fully-instrumented engine equal the untraced engine's.
- - Request spans + engine events export as Chrome trace-event JSON with
-   the segment / chunk / admission / retirement timeline intact.
+ - Host spans (``telemetry.span``) land in a ``jax.profiler`` trace as
+   ``TraceAnnotation`` events and, with a Telemetry bound, in its ring at
+   the same instant: both read ``time.time_ns``, the profiler's clock.
+ - Request spans + the engine's ``serve.*`` phase spans export as Chrome
+   trace-event JSON with the segment / chunk / admission / retirement
+   timeline intact and nested, on the profiler's clock.
  - The metrics registry exports Prometheus text that agrees with
    ``summarize()`` and ``health()`` by construction (same feed paths).
  - The compile watcher turns the documented recompilation contract into
@@ -17,6 +21,7 @@
    zeroed) but KEEPS the compile log.
 """
 import json
+import time
 from collections import Counter as TallyCounter
 
 import numpy as np
@@ -26,7 +31,7 @@ from repro.configs import get_config, reduced
 from repro.inference.config import ServingConfig
 from repro.inference.scheduler import ContinuousEngine, Request, summarize
 from repro.inference.telemetry import (MetricsRegistry, Telemetry,
-                                       _signature)
+                                       _signature, span)
 from repro.models.transformer import init_model
 
 MAX_LEN = 96
@@ -99,6 +104,48 @@ def test_compile_watch_signature_and_passthrough():
     assert _signature((a32,), {}) == (((2, 3), "int32"),)
 
 
+# -- host spans on the profiler's clock --------------------------------------
+
+
+def test_span_lands_on_profiler_clock(tmp_path):
+    """A span is one ``TraceAnnotation``: under a ``jax.profiler`` trace it
+    appears in the ``/host:CPU`` plane, nested as opened, and the ring
+    event a bound Telemetry records for it starts at the same instant on
+    the same clock (Unix-epoch ns, ``profile_start_time`` + offset)."""
+    import jax
+    from jax.profiler import ProfileData
+    tel = Telemetry()
+    jax.profiler.start_trace(str(tmp_path))
+    with span(tel, "serve.segment", kind="decode_segment") as sp:
+        with span(None, "serve.segment.wait"):
+            time.sleep(0.005)
+        sp.args["tokens"] = 3
+    jax.profiler.stop_trace()
+    with pytest.raises(KeyError):         # a span passes exceptions on
+        with span(tel, "serve.admit"):
+            raise KeyError("x")
+
+    path = sorted(tmp_path.glob("**/*.xplane.pb"))[-1]
+    start, host = None, {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        if plane.name == "Task Environment":
+            start = dict(plane.stats)["profile_start_time"]
+        elif plane.name == "/host:CPU":
+            host.update({e.name: (e.start_ns, e.duration_ns)
+                         for line in plane.lines for e in line.events
+                         if e.name.startswith("serve.")})
+    assert set(host) == {"serve.segment", "serve.segment.wait"}
+    (s0, d0), (s1, d1) = host["serve.segment"], host["serve.segment.wait"]
+    assert s0 <= s1 and s1 + d1 <= s0 + d0 and d1 >= 5e6
+    # the ring holds the bound spans only (the wait span had no Telemetry)
+    ring = {e["name"]: e for e in tel.events}
+    assert set(ring) == {"serve.segment", "serve.admit"}
+    ev = ring["serve.segment"]
+    assert ev["args"] == {"kind": "decode_segment", "tokens": 3}
+    assert abs(ev["ts"] * 1e3 - (start + s0)) < 1e6      # within 1 ms
+    assert abs(ev["dur"] * 1e3 - d0) < 1e6
+
+
 # -- bitwise inertness + end-to-end spans/trace ------------------------------
 
 
@@ -127,7 +174,9 @@ def test_chrome_trace_structure_and_prometheus_consistency(dense):
     ce = ContinuousEngine(cfg, params, slots=2, max_len=MAX_LEN, seg_len=4,
                           telemetry=tel)
     reqs = _mk_requests(cfg.vocab, SHAPES)
+    t_lo = time.time_ns() / 1e3
     results = ce.serve(reqs)
+    t_hi = time.time_ns() / 1e3
     s = summarize(results, max(r.finish_s for r in results))
 
     trace = tel.chrome_trace()
@@ -146,16 +195,35 @@ def test_chrome_trace_structure_and_prometheus_consistency(dense):
         assert span[0]["dur"] >= 0
     assert names.count("submit") == len(reqs)
     assert names.count("first_token") == len(reqs)
-    assert any(e["name"] == "decode_segment" and e["ph"] == "X"
-               for e in evs)
-    assert any(n.startswith("chunk_burst") for n in names)
-    assert any(n.startswith("admit[") for n in names)
+    # the engine's phase spans, each carrying what its on_* hook adds
+    segs = [e for e in evs if e["name"] == "serve.segment"]
+    assert segs and all(e["ph"] == "X" for e in segs)
+    assert all(e["args"]["kind"] == "decode_segment" for e in segs)
+    bursts = [e for e in evs if e["name"] == "serve.chunk_burst"]
+    assert bursts and all(e["args"]["chunks"] >= 1 for e in bursts)
+    staging = [e for e in evs if e["name"] == "serve.admit.staging"]
+    assert staging and all(e["args"]["kind"] == "chunked"
+                           for e in staging)
+    assert sum(e["args"]["n"] for e in staging) == len(reqs)
+    assert any(e["name"] == "serve.admit" for e in evs)
     assert any(n.startswith("compile[") for n in names)
+    # children nest inside their parent span
+    for child, parent in (("serve.segment.dispatch", segs),
+                          ("serve.segment.emit", segs),
+                          ("serve.insert", bursts)):
+        kids = [e for e in evs if e["name"] == child]
+        assert kids, child
+        for k in kids:
+            assert any(p["ts"] <= k["ts"]
+                       and k["ts"] + k["dur"] <= p["ts"] + p["dur"]
+                       for p in parent), child
     # metadata rows make the pids/tids human-named in perfetto
     assert any(e["ph"] == "M" and e["name"] == "process_name"
                for e in evs)
-    # every non-meta event sits on the telemetry's own epoch (>= 0)
-    assert all(e["ts"] >= 0 for e in evs if e["ph"] != "M")
+    # every non-meta event sits on the profiler's clock: Unix-epoch
+    # microseconds from time.time_ns, inside the serve() call
+    assert trace["otherData"]["clock"] == "time.time_ns"
+    assert all(t_lo <= e["ts"] <= t_hi for e in evs if e["ph"] != "M")
 
     # prometheus snapshot agrees with summarize() and health() because
     # the registry is fed from the same single retirement path
@@ -166,6 +234,10 @@ def test_chrome_trace_structure_and_prometheus_consistency(dense):
             == s["delivered_tokens"])
     n_ttft, _ = tel.metrics.value("serving_ttft_seconds")
     assert n_ttft == len(reqs)
+    n_wait, mean_wait = tel.metrics.value("serving_queue_wait_seconds")
+    assert n_wait == len(reqs)
+    assert mean_wait == pytest.approx(
+        np.mean([r.admit_s - r.arrival_s for r in results]))
     h = ce.health()
     assert f'serving_health_segments {float(h["segments"])}' in text
     assert f'serving_health_failed {float(h["failed"])}' in text
