@@ -74,11 +74,12 @@ geometry (selection top-k depends on max_len, so the equivalence requires
 equal ``max_len``).  Pinned by tests/test_scheduler.py.
 
 Recompilation contract: one compile per prompt bucket for the chunk step
-(at admission widths 1 and ``slots``), slot insertion, and the legacy
-prefill; one compile total for the decode segment.  Per-request dsa_mode
-overrides add one compile per DISTINCT MODE actually used for the
-segment/chunk/prefill programs.  Nothing recompiles per request, per
-n_new, per temperature, per arrival pattern, or per burst size.
+and its staging-cache build (at admission widths 1 and ``slots``), slot
+insertion, and the legacy prefill; one compile total for the decode
+segment.  Per-request dsa_mode overrides add one compile per DISTINCT
+MODE actually used for the segment/chunk/prefill programs.  Nothing
+recompiles per request, per n_new, per temperature, per arrival
+pattern, or per burst size.
 ``warmup`` precompiles the fixed chunk-shape set for its prompt buckets.
 
 Fault tolerance: every request retires with a typed ``RequestResult.status``
@@ -138,7 +139,7 @@ import numpy as np
 
 from repro.configs.base import ArchConfig
 from repro.distributed.fault_tolerance import StepWatchdog
-from repro.distributed.sharding import is_spec_leaf, shard, shard_put_tree
+from repro.distributed.sharding import is_spec_leaf, shard, tree_specs
 from repro.inference.config import ServingConfig, resolve_config
 from repro.inference.engine import Engine, _ro_view, _sample, \
     can_chunk_prefill, can_page, pow2_bucket
@@ -669,6 +670,8 @@ class ContinuousEngine:
         # lifecycle and segment/chunk/fault events land on a trace
         # timeline, and once per ``sample_every`` segments a sel_probe
         # replay samples the DSA block selection (see _sparsity_probe).
+        self._builders: Dict[tuple, object] = {}  # _new_cache's programs
+        self._staging = self._new_cache
         self.telemetry = c.telemetry
         self._probe = None              # lazily-built sparsity probe jit
         self._probe_prev: Dict[int, tuple] = {}   # slot -> (rid, blocks)
@@ -682,6 +685,7 @@ class ContinuousEngine:
             self._seed = tel.wrap_jit("seed", self._seed)
             self._segment = tel.wrap_jit("segment", self._segment)
             self._chunk = tel.wrap_jit("chunk", self._chunk)
+            self._staging = tel.wrap_jit("staging", self._staging)
 
         self.queue: deque = deque()
         self.reset()     # resident caches + host mirrors of device carries
@@ -696,12 +700,40 @@ class ContinuousEngine:
         """Slot-axis carry -> mesh (identity without one)."""
         return self.engine.put_batch(x)
 
-    def _put_cache(self, caches):
-        """Unstacked cache tree -> mesh (identity without one)."""
-        if self.mesh is None:
-            return caches
-        return shard_put_tree(caches, unstacked_cache_specs(self.cfg, caches),
-                              self.mesh, self.engine.shard_rules)
+    def _cache_init(self, batch: int, rows: int, pages: Optional[int]):
+        """The traceable build of a zeroed unstacked cache tree."""
+        def new_cache():
+            return unstack_group_caches(init_cache(
+                self.cfg, batch, rows, self.engine.decode_flags,
+                dtype=self.engine.cache_dtype, pages=pages))
+        return new_cache
+
+    def _new_cache(self, batch: int, rows: int,
+                   pages: Optional[int] = None):
+        """A zeroed unstacked per-layer cache tree of ``batch`` x ``rows``
+        (a paged pool of ``pages`` pages when given), built by ONE
+        compiled program per geometry with no array inputs: one dispatch,
+        and XLA folds ``init_cache``'s layer broadcast and the per-layer
+        slices into one zero fill per output buffer (built eagerly, the
+        tree costs several dispatches per leaf and layer, and a stacked
+        transient).  On a mesh every leaf lands with the sharding
+        ``shard_put_tree`` resolves for it."""
+        key = (batch, rows, pages)
+        fn = self._builders.get(key)
+        if fn is None:
+            build = self._cache_init(batch, rows, pages)
+            kw = {}
+            if self.mesh is not None:
+                shape = jax.eval_shape(build)
+                specs = tree_specs(shape,
+                                   unstacked_cache_specs(self.cfg, shape),
+                                   rules=self.engine.shard_rules,
+                                   mesh=self.mesh)
+                kw["out_shardings"] = jax.tree.map(
+                    lambda _, s: jax.sharding.NamedSharding(self.mesh, s),
+                    shape, specs)
+            fn = self._builders[key] = jax.jit(build, **kw)
+        return fn()
 
     # -- queue / admission --------------------------------------------------
 
@@ -1097,9 +1129,8 @@ class ContinuousEngine:
                 self.stats["prefix_hits"] += len(group)
                 self.stats["prefix_tokens_reused"] += skip * c * len(group)
         with span(self.telemetry, "serve.admit.staging") as sp:
-            caches = self._put_cache(unstack_group_caches(
-                init_cache(self.cfg, bpf, bucket, self.engine.decode_flags,
-                           dtype=self.engine.cache_dtype)))
+            caches = self._staging(bpf, bucket)
+            self.stats["staging_builds"] += 1
             if skip > 0:
                 rpages = jnp.asarray(
                     shared[:skip * c // self._page_rows], jnp.int32)
@@ -1473,11 +1504,9 @@ class ContinuousEngine:
         its prefix registry are rebuilt with it)."""
         self.pool = (PagePool(self.pool_pages, self._page_rows)
                      if self.paged else None)
-        caches = unstack_group_caches(
-            init_cache(self.cfg, self.slots, self.max_len,
-                       self.engine.decode_flags,
-                       dtype=self.engine.cache_dtype,
-                       pages=self.pool_pages if self.paged else None))
+        pages = self.pool_pages if self.paged else None
+        shape = jax.eval_shape(self._cache_init(self.slots, self.max_len,
+                                                pages))
 
         def record(path, log):
             name = _leaf_name(path)
@@ -1485,9 +1514,9 @@ class ContinuousEngine:
                 self._cache_logical[name] = tuple(log)
 
         jax.tree_util.tree_map_with_path(
-            record, unstacked_cache_specs(self.cfg, caches),
+            record, unstacked_cache_specs(self.cfg, shape),
             is_leaf=is_spec_leaf)
-        self._caches = self._put_cache(caches)
+        self._caches = self._new_cache(self.slots, self.max_len, pages)
         self._tok = np.zeros((self.slots, 1), np.int32)
         self._keys = np.zeros((self.slots, 2), np.uint32)
         self._active = np.zeros((self.slots,), bool)
@@ -1512,6 +1541,7 @@ class ContinuousEngine:
                       "spec_rounds": 0, "spec_emitted": 0, "draft_s": 0.0,
                       "accept_hist": [0] * (self.spec + 1),
                       "prefix_hits": 0, "prefix_tokens_reused": 0,
+                      "staging_builds": 0,
                       "shed": 0, "cancelled": 0, "timeout": 0, "failed": 0,
                       "dispatch_failures": 0, "proposer_failures": 0,
                       "watchdog_slow": 0}
@@ -1595,9 +1625,7 @@ class ContinuousEngine:
             p = np.asarray(prompts[min(j, n - 1)], np.int32)
             mat[j, :len(p)] = p
             lengths[j] = len(p)
-        caches = self._put_cache(unstack_group_caches(
-            init_cache(self.cfg, bpf, bucket, self.engine.decode_flags,
-                       dtype=self.engine.cache_dtype)))
+        caches = self._staging(bpf, bucket)
         flags = self._flags(self.engine.decode_flags.dsa_mode)
         active = self._put_b(np.ones((bpf,), bool))
         prompt_logits = np.zeros((bpf, self.cfg.vocab), np.float32)
@@ -1639,9 +1667,7 @@ class ContinuousEngine:
         bucket = self.engine.prompt_bucket(bucket)
         c = min(self.chunk_tokens, pow2_bucket(bucket, self._chunk_floor))
         bpf = width or self.slots
-        caches = self._put_cache(unstack_group_caches(
-            init_cache(self.cfg, bpf, bucket, self.engine.decode_flags,
-                       dtype=self.engine.cache_dtype)))
+        caches = self._staging(bpf, bucket)
         with self._ctx():
             lowered = self._chunk.lower(
                 self.engine.params, caches,

@@ -84,6 +84,29 @@ def test_resident_cache_is_sharded_over_data(dense_pair):
     assert len(leaf.sharding.device_set) == jax.device_count()
 
 
+def test_compiled_cache_builds_land_sharded(dense_pair, mesh):
+    """The compiled cache builder lands every leaf with the sharding
+    ``shard_put_tree`` gives the eagerly built tree: staging caches at
+    both admission widths and the resident cache."""
+    from repro.distributed.sharding import shard_put_tree
+    from repro.models.transformer import init_cache, unstack_group_caches, \
+        unstacked_cache_specs
+    cfg, _, _, sharded = dense_pair
+    for batch, rows in ((1, 64), (SLOTS, 64), (SLOTS, MAX_LEN)):
+        got = (sharded._caches if rows == MAX_LEN
+               else sharded._new_cache(batch, rows))
+        eager = unstack_group_caches(init_cache(
+            cfg, batch, rows, sharded.engine.decode_flags,
+            dtype=sharded.engine.cache_dtype))
+        want = shard_put_tree(eager, unstacked_cache_specs(cfg, eager),
+                              mesh, sharded.engine.shard_rules)
+        assert jax.tree.structure(got) == jax.tree.structure(want)
+        for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            assert g.sharding == w.sharding, (batch, rows, g.shape)
+            assert g.shape == w.shape and g.dtype == w.dtype
+            assert not np.asarray(g).any()
+
+
 def test_sharded_run_bitwise_chunked_and_segments(dense_pair):
     """Chunked admission + plain decode segments, mixed lengths and
     n_new=1 retire-at-admission requests: the sharded engine's tokens are

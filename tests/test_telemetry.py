@@ -274,8 +274,9 @@ def test_engine_reset_resets_registry_keeps_compile_log(dense):
 @pytest.mark.parametrize("variant", ["dense", "paged", "quant", "spec"])
 def test_recompilation_contract(dense, variant):
     """THE fixed-compile-set contract as one assertion per engine family:
-    ``warmup`` over two prompt buckets compiles one chunk + one insert
-    program per (bucket, group-width in {1, slots}) and ONE decode
+    ``warmup`` over two prompt buckets compiles one chunk, one staging
+    build and one insert program per (bucket, group-width in {1, slots})
+    and ONE decode
     segment (speculative engines compile ONE verify and no segment —
     spec segments always run when the batch is in the envelope); mixed
     traffic afterwards adds ZERO new compiles.  ``zero_pages``/``seed``
@@ -291,6 +292,7 @@ def test_recompilation_contract(dense, variant):
     tally = TallyCounter(p for p, _, _ in tel.compiles)
     insert = "insert_paged" if variant == "paged" else "insert"
     assert tally["chunk"] == 4               # 2 buckets x widths {1, slots}
+    assert tally["staging"] == 4             # their staging-cache builds
     assert tally[insert] == 4
     if variant == "spec":
         assert tally["verify"] == 1 and tally["segment"] == 0
