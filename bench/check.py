@@ -1,7 +1,8 @@
 """The comparison that decides a run's ``correct``.
 
 After the window has closed and the serving engine is freed, the plain
-reference (``bench/reference.py``) is run over each compared request (up
+reference (the module the configuration names, by default
+``bench/reference.py``) is run over each compared request (up
 to ``check.sample`` completed requests, drawn from the seed, the longest
 prompt always among them), teacher-forced over the tokens the engine
 served: it gives the logits at every position that produced a served
@@ -51,9 +52,11 @@ def gaps(logits: np.ndarray, tokens: np.ndarray) -> np.ndarray:
 
 
 def readings(weights, arch: dict, max_len: int, max_new: int, prompts,
-             picked, precision: str = "float32") -> Dict[str, float]:
+             picked, precision: str = "float32",
+             ref=reference) -> Dict[str, float]:
     """The compared numbers over every served token of ``picked`` results
-    (``prompts``: rid -> prompt; ``max_new``: the mix's longest output).
+    (``prompts``: rid -> prompt; ``max_new``: the mix's longest output),
+    against the reference module ``ref``.
     With ``precision`` other than float32 the token compared at each
     position is the one that precision's reference puts first, over the
     same prompts and served tokens: the reading of the lower-precision
@@ -61,13 +64,13 @@ def readings(weights, arch: dict, max_len: int, max_new: int, prompts,
     g = []
     for r in picked:
         tok = np.asarray(r.tokens, np.int64)
-        ref = reference.served_logits(weights, arch, max_len, prompts[r.rid],
-                                      tok, max_new)
+        want = ref.served_logits(weights, arch, max_len, prompts[r.rid], tok,
+                                 max_new)
         if precision != "float32":
-            tok = reference.served_logits(weights, arch, max_len,
-                                          prompts[r.rid], tok, max_new,
-                                          precision=precision).argmax(-1)
-        g.append(gaps(ref, tok))
+            tok = ref.served_logits(weights, arch, max_len, prompts[r.rid],
+                                    tok, max_new,
+                                    precision=precision).argmax(-1)
+        g.append(gaps(want, tok))
     g = np.concatenate(g)
     return {"widest_gap": float(g.max()), "mean_gap": float(g.mean()),
             "requests": len(picked), "tokens": int(g.size)}

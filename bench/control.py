@@ -2,6 +2,7 @@
 runs and the lower-precision control, many seeds in one process.
 
     python3 bench/control.py --workload <cell> --seconds <s> --seeds 1 2 3 ...
+        [--root <tree>]
 
 For each seed: weights and requests from the seed, planted as the cell
 says, one ``serve`` of the cell's traffic for ``--seconds`` through one
@@ -9,8 +10,8 @@ engine (built and warmed once; only the weights change between seeds),
 then on the same sampled requests (``check.sample``) the compared numbers
 of the served tokens against the float32 reference (``sound``), and of the
 tokens an fp8 reference puts first at each position of the same prompts
-and served tokens (``control``,
-``reference.served_logits(precision="fp8")``).  One JSON line per seed,
+and served tokens (``control``, the configuration's reference's
+``served_logits(precision="fp8")``).  One JSON line per seed,
 with the seconds each reading took.  The benchmark's own runs never run
 this.
 """
@@ -41,9 +42,9 @@ def readings(c, eng, w, seed: int, seconds: float) -> dict:
     args = (w, c.arch, c.cell["serving"]["max_len"], c.mix["output"]["max"],
             prompts, picked)
     t0 = time.monotonic()
-    sound = check.readings(*args)
+    sound = check.readings(*args, ref=c.reference)
     t1 = time.monotonic()
-    control = check.readings(*args, precision="fp8")
+    control = check.readings(*args, precision="fp8", ref=c.reference)
     return {"seed": seed, "requests": len(results),
             "ok": sum(r.status == "ok" for r in results),
             "sound": sound, "control": control,
@@ -55,10 +56,13 @@ def main(argv=None) -> int:
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--seeds", type=int, nargs="+", required=True)
+    ap.add_argument("--root", type=Path, default=bench_run.ROOT,
+                    help="the tree whose BENCHMARK.json names the cell "
+                         "(default: this checkout)")
     args = ap.parse_args(argv)
-    c = bench_run.load_cell(args.workload)
+    c = bench_run.load_cell(args.workload, args.root)
     import jax
-    from bench import reference, weights
+    from bench import weights
     jax.config.update("jax_compilation_cache_dir", str(bench_run.CACHE_DIR))
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     devices = jax.devices()
@@ -69,8 +73,8 @@ def main(argv=None) -> int:
     plant = c.cell.get("plant")
     w = weights.make(c.arch, args.seeds[0], plant)
     eng = bench_run.build_engine(cfg, w, c.cell["serving"])
-    bucket = reference.geometry(c.arch, c.cell["serving"]["max_len"],
-                                c.mix["prompt"]["max"])["bucket"]
+    bucket = c.reference.geometry(c.arch, c.cell["serving"]["max_len"],
+                                  c.mix["prompt"]["max"])["bucket"]
     eng.warmup([bucket])
     bench_run.warm_page_zeroing(eng, c.mix["prompt"]["max"]
                                 + c.mix["output"]["max"])

@@ -2,9 +2,11 @@
 
 A mix is a JSON file of parameters:
 
-- ``arrival``: ``"poisson"``, open loop at ``rate_rps``;
+- ``arrival``: ``"poisson"``, open loop at ``rate_rps``, or ``"batch"``,
+  an offline batch: every request arrives at t=0;
 - ``rate_rps``: requests per second; a run of ``seconds`` sends
-  ``round(rate_rps * seconds)`` requests;
+  ``round(rate_rps * seconds)`` requests (a batch cell's rate is the
+  capacity it measures, so the batch lasts about ``seconds``);
 - ``prompt`` and ``output``: token-length distributions,
   ``{"dist": "lognormal", "median": m, "sigma": s, "min": a, "max": b}``
   (clipped to [a, b]).
@@ -23,6 +25,14 @@ prompt, a number of rows to marker tokens: the counts of
 ``plant["block_markers"]`` in a seeded order, one count to a block, so no
 two blocks of a prompt hold the same number.  The last, partial block
 holds none.
+
+With ``plant["graded"]`` (``{"levels": [...], "rows": r}``) the marker id
+``lo + g`` carries channel-0 level ``levels[g]`` (``bench/weights.py``)
+and the plant is graded, for prompts longer than a ladder of counts
+reaches: up to ``len(levels)`` whole blocks, drawn from the seed among all
+but the first, each get ``r`` rows of one level, no two blocks the same
+level; every other block holds no marker.  A block's largest row score
+and its sum then order blocks alike, by level.
 """
 from __future__ import annotations
 
@@ -47,8 +57,9 @@ class Spec:
     arrival_s: float
 
 
-def load(name: str) -> dict:
-    return json.loads((HERE / "traffic" / f"{name}.json").read_text())
+def load(name: str, bench: Path = HERE) -> dict:
+    """The traffic mix ``<bench>/traffic/<name>.json``."""
+    return json.loads((bench / "traffic" / f"{name}.json").read_text())
 
 
 def count(mix: dict, seconds: float) -> int:
@@ -70,6 +81,8 @@ def lengths(dist: dict, n: int) -> np.ndarray:
 
 def gaps(mix: dict, n: int) -> np.ndarray:
     """The n inter-arrival gaps (seconds), ascending."""
+    if mix["arrival"] == "batch":
+        return np.zeros(n)
     if mix["arrival"] != "poisson":
         raise ValueError(f"unknown arrival process {mix['arrival']!r}")
     return -np.log1p(-_quantiles(n)) / mix["rate_rps"]
@@ -80,6 +93,9 @@ def plant(tokens: np.ndarray, rng, plant: dict, block_k: int) -> None:
     docstring), in place."""
     lo, hi = plant["marker_ids"]
     n_full = len(tokens) // block_k
+    if "graded" in plant:
+        _graded(tokens, rng, lo, plant["graded"], n_full, block_k)
+        return
     ladder = plant["block_markers"]
     if n_full > len(ladder):
         raise ValueError(f"a prompt of {len(tokens)} tokens has {n_full} "
@@ -88,6 +104,20 @@ def plant(tokens: np.ndarray, rng, plant: dict, block_k: int) -> None:
     for b, m in enumerate(counts):
         rows = b * block_k + rng.choice(block_k, size=int(m), replace=False)
         tokens[rows] = rng.integers(lo, hi, size=int(m))
+
+
+def _graded(tokens, rng, lo: int, graded: dict, n_full: int,
+            block_k: int) -> None:
+    """The graded plant (module docstring), in place."""
+    n = min(len(graded["levels"]), n_full - 1)
+    if n <= 0:
+        return
+    blocks = 1 + rng.choice(n_full - 1, size=n, replace=False)
+    grades = rng.permutation(len(graded["levels"]))[:n]
+    for b, g in zip(blocks, grades):
+        rows = b * block_k + rng.choice(block_k, size=graded["rows"],
+                                        replace=False)
+        tokens[rows] = lo + g
 
 
 def generate(mix: dict, seconds: float, seed: int, vocab: int,

@@ -35,6 +35,7 @@ the precision below the one it states.
 from __future__ import annotations
 
 import functools
+import json
 import math
 
 import jax
@@ -128,16 +129,81 @@ def _topk_mask(scores, forced, valid, nb_keep):
     return keep | hit.any(-2)
 
 
-@functools.partial(jax.jit, static_argnames=("arch_t", "geo_t", "precision"))
-def _layer(w, xp, xd, plen, nd, *, arch_t, geo_t, precision):
+def predict(w_attn, h, bits: int, mm):
+    """The DSA prediction path of normed rows h: (Q~, K~)."""
+    xq = _q(mm(h, w_attn["dsa"]["p"]), bits)
+    qt = _q(mm(xq, _q(w_attn["dsa"]["wq"].astype(jnp.float32), bits)), bits)
+    kt = _q(mm(xq, _q(w_attn["dsa"]["wk"].astype(jnp.float32), bits)), bits)
+    return qt, kt
+
+
+def prompt_scores(qtp, ktp, geo: dict):
+    """(nQb, nKb) block scores of the padded prompt rows: each query
+    block's mean Q~ against every row's K~, the largest row of each key
+    block (pad rows included)."""
+    bq, bk = geo["block_q"], geo["block_k"]
+    p_rows = qtp.shape[0]
+    n_qb, n_kb_p = p_rows // bq, p_rows // bk
+    q_blk = qtp.reshape(n_qb, bq, -1).mean(1)
+    sc = jnp.einsum("qc,sc->qs", q_blk, ktp,
+                    precision=jax.lax.Precision.HIGHEST)
+    return sc.reshape(n_qb, n_kb_p, bk).max(-1)
+
+
+def prompt_keep(bs, geo: dict):
+    """(nQb, nKb) bool: the key blocks each prompt query block keeps."""
+    n_qb, n_kb_p = bs.shape
+    qi = jnp.arange(n_qb)[:, None]
+    kj = jnp.arange(n_kb_p)[None, :]
+    return _topk_mask(bs, kj > qi - geo["local_blocks"] - 1, kj <= qi,
+                      geo["nb_keep_pre"])
+
+
+def decode_scores(qtd, ktp, ktd, plen, nd, geo: dict):
+    """(D, nKb) block scores of the decode rows (positions plen, plen+1,
+    ...; the first ``nd`` real): Q~ against each block's K~ sum over the
+    real rows the step has seen, over block_k."""
+    bk, n_kb = geo["block_k"], geo["n_kb_dec"]
+    pos_p = jnp.arange(ktp.shape[0])
+    pos_d = plen + jnp.arange(ktd.shape[0])
+    blk_p = jax.nn.one_hot(pos_p // bk, n_kb, dtype=jnp.float32)
+    ktb_p = jnp.einsum("sj,sc->jc", blk_p * (pos_p < plen)[:, None], ktp,
+                       precision=jax.lax.Precision.HIGHEST)
+    live_d = (jnp.arange(ktd.shape[0]) < nd)[:, None, None]
+    blk_d = jax.nn.one_hot(pos_d // bk, n_kb, dtype=jnp.float32)
+    ktb = ktb_p[None] + jnp.cumsum(
+        jnp.where(live_d, blk_d[:, :, None] * ktd[:, None, :], 0.0), 0)
+    return jnp.einsum("tc,tjc->tj", qtd, ktb,
+                      precision=jax.lax.Precision.HIGHEST) / bk
+
+
+def decode_keep(s_blk, kv_len, geo: dict):
+    """(D, nKb) bool: the cache blocks each decode row keeps, at
+    ``kv_len`` (D,) rows of cache."""
+    bk = geo["block_k"]
+    jj = jnp.arange(geo["n_kb_dec"])[None, :]
+    valid = jj * bk < kv_len[:, None]
+    recent = (jj + 1) * bk > kv_len[:, None] - geo["decode_local"]
+    return _topk_mask(s_blk, recent, valid, geo["nb_keep_dec"])
+
+
+def swiglu(w_mlp, h, arch: dict, mm):
+    """The configuration's MLP: SwiGLU."""
+    u = jax.nn.silu(mm(h, w_mlp["w1"])) * mm(h, w_mlp["w3"])
+    return mm(u, w_mlp["w2"])
+
+
+@functools.partial(jax.jit, static_argnames=("arch_t", "geo_t", "precision",
+                                             "mlp"))
+def _layer(w, xp, xd, plen, nd, *, arch_t, geo_t, precision, mlp):
     """One layer over the padded prompt rows xp (P, d) and the decode rows
     xd (D, d): the prompt's real length is ``plen``, the first ``nd`` decode
-    rows are real (positions plen, plen+1, ...)."""
-    arch, geo = dict(arch_t), dict(geo_t)
-    dsa = dict(arch["dsa"])
+    rows are real (positions plen, plen+1, ...); ``mlp(w["mlp"], h, arch,
+    mm)`` is the block's MLP."""
+    arch, geo = arch_t.get(), geo_t.get()
     hq, hkv, hd = arch["n_heads"], arch["n_kv_heads"], arch["head_dim"]
     bq, bk = geo["block_q"], geo["block_k"]
-    bits = dsa["quant_bits"]
+    bits = arch["dsa"]["quant_bits"]
     p_rows, d_rows = xp.shape[0], xd.shape[0]
     pos_p = jnp.arange(p_rows)
     pos_d = plen + jnp.arange(d_rows)
@@ -150,26 +216,15 @@ def _layer(w, xp, xd, plen, nd, *, arch_t, geo_t, precision):
         k = _rope(mm(h, w["attn"]["wk"]).reshape(-1, hkv, hd), pos,
                   arch["rope_theta"])
         v = mm(h, w["attn"]["wv"]).reshape(-1, hkv, hd)
-        xq = _q(mm(h, w["attn"]["dsa"]["p"]), bits)
-        qt = _q(mm(xq, _q(w["attn"]["dsa"]["wq"].astype(jnp.float32), bits)),
-                bits)
-        kt = _q(mm(xq, _q(w["attn"]["dsa"]["wk"].astype(jnp.float32), bits)),
-                bits)
+        qt, kt = predict(w["attn"], h, bits, mm)
         return q, k, v, qt, kt
 
     qp, kp, vp, qtp, ktp = proj(xp, pos_p)
     qd, kd, vd, qtd, ktd = proj(xd, pos_d)
 
     # prompt rows: block selection per query block (pad rows take part)
-    n_qb, n_kb_p = p_rows // bq, p_rows // bk
-    q_blk = qtp.reshape(n_qb, bq, -1).mean(1)
-    sc = jnp.einsum("qc,sc->qs", q_blk, ktp,
-                    precision=jax.lax.Precision.HIGHEST)
-    bs = sc.reshape(n_qb, n_kb_p, bk).max(-1)
-    qi = jnp.arange(n_qb)[:, None]
-    kj = jnp.arange(n_kb_p)[None, :]
-    keep_p = _topk_mask(bs, kj > qi - geo["local_blocks"] - 1, kj <= qi,
-                        geo["nb_keep_pre"])                 # (nQb, nKb)
+    n_qb = p_rows // bq
+    keep_p = prompt_keep(prompt_scores(qtp, ktp, geo), geo)  # (nQb, nKb)
 
     def prompt_block(i):
         rows = i * bq + jnp.arange(bq)
@@ -182,21 +237,8 @@ def _layer(w, xp, xd, plen, nd, *, arch_t, geo_t, precision):
     att_p = jax.lax.map(prompt_block, jnp.arange(n_qb)).reshape(p_rows, -1)
 
     # decode rows: K~ block sums over the real rows each step has seen
-    n_kb = geo["n_kb_dec"]
-    blk_p = jax.nn.one_hot(pos_p // bk, n_kb, dtype=jnp.float32)
-    ktb_p = jnp.einsum("sj,sc->jc", blk_p * (pos_p < plen)[:, None], ktp,
-                       precision=jax.lax.Precision.HIGHEST)
-    live_d = (jnp.arange(d_rows) < nd)[:, None, None]
-    blk_d = jax.nn.one_hot(pos_d // bk, n_kb, dtype=jnp.float32)
-    ktb = ktb_p[None] + jnp.cumsum(
-        jnp.where(live_d, blk_d[:, :, None] * ktd[:, None, :], 0.0), 0)
-    s_blk = jnp.einsum("tc,tjc->tj", qtd, ktb,
-                       precision=jax.lax.Precision.HIGHEST) / bk
-    kv_len = pos_d + 1
-    jj = jnp.arange(n_kb)[None, :]
-    valid = jj * bk < kv_len[:, None]
-    recent = (jj + 1) * bk > kv_len[:, None] - geo["decode_local"]
-    keep_d = _topk_mask(s_blk, recent, valid, geo["nb_keep_dec"])  # (D, nKb)
+    keep_d = decode_keep(decode_scores(qtd, ktp, ktd, plen, nd, geo),
+                         pos_d + 1, geo)                     # (D, nKb)
     kpos = jnp.concatenate([pos_p, pos_d])
     kreal = jnp.concatenate([pos_p < plen, jnp.arange(d_rows) < nd])
     seen = kreal[None, :] & (kpos[None, :] <= pos_d[:, None])   # (D, S)
@@ -207,8 +249,7 @@ def _layer(w, xp, xd, plen, nd, *, arch_t, geo_t, precision):
     def finish(x, att):
         x = x + mm(att, w["attn"]["wo"])
         h = _rms(x, w["norm2"].astype(jnp.float32), arch["norm_eps"])
-        u = jax.nn.silu(mm(h, w["mlp"]["w1"])) * mm(h, w["mlp"]["w3"])
-        return x + mm(u, w["mlp"]["w2"])
+        return x + mlp(w["mlp"], h, arch, mm)
 
     return finish(xp, att_p), finish(xd, att_d)
 
@@ -219,17 +260,32 @@ def _head(w_norm, w_head, x, *, eps, precision):
     return _mm(h, w_head, precision)
 
 
-def _freeze(d: dict) -> tuple:
-    return tuple((k, _freeze(v) if isinstance(v, dict) else v)
-                 for k, v in sorted(d.items()))
+class _Static:
+    """A configuration dict as a static argument of ``jax.jit``: hashed
+    and compared by its content."""
+
+    def __init__(self, d: dict):
+        self.key = json.dumps(d, sort_keys=True)
+
+    def __hash__(self):
+        return hash(self.key)
+
+    def __eq__(self, other):
+        return isinstance(other, _Static) and other.key == self.key
+
+    def get(self) -> dict:
+        return json.loads(self.key)
 
 
 def served_logits(weights, arch: dict, max_len: int, prompt, served,
-                  max_new: int, precision: str = "float32") -> np.ndarray:
+                  max_new: int, precision: str = "float32",
+                  mlp=swiglu) -> np.ndarray:
     """Logits (len(served), vocab) float32 at the positions that produced
     each served token: the last prompt row, then each decode step fed the
     previous served token.  Runs layer by layer; ``max_new`` fixes the
-    decode rows' static size so every request reuses one compile."""
+    decode rows' static size so every request reuses one compile.  A
+    model whose blocks differ only in their MLP passes its own ``mlp``
+    (``swiglu``'s signature)."""
     prompt = np.asarray(prompt, np.int32)
     served = np.asarray(served, np.int32)
     plen, n = len(prompt), len(served)
@@ -243,11 +299,12 @@ def served_logits(weights, arch: dict, max_len: int, prompt, served,
     xp = emb[jnp.asarray(toks_p)].astype(jnp.float32)
     xd = emb[jnp.asarray(toks_d)].astype(jnp.float32)
     g = weights["groups"]["b0"]
-    arch_t, geo_t = _freeze(arch), _freeze(geo)
+    arch_t, geo_t = _Static(arch), _Static(geo)
     for i in range(arch["n_layers"]):
         wl = jax.tree.map(lambda a: a[i], g)
         xp, xd = _layer(wl, xp, xd, jnp.int32(plen), jnp.int32(n - 1),
-                        arch_t=arch_t, geo_t=geo_t, precision=precision)
+                        arch_t=arch_t, geo_t=geo_t, precision=precision,
+                        mlp=mlp)
     rows = jnp.concatenate([xp[plen - 1:plen], xd[:n - 1]])
     logits = _head(weights["final_norm"], weights["lm_head"], rows,
                    eps=arch["norm_eps"], precision=precision)

@@ -2,9 +2,16 @@
 
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
-A cell of ``BENCHMARK.json`` names a configuration (``bench/configs/``) and
-a traffic mix (``bench/traffic/``); its serving settings and correctness
-limits are in ``bench/cells/<cell>.json``.  One run:
+A cell of ``BENCHMARK.json`` names a configuration (the file its
+``configs`` entry gives) and a traffic mix (``bench/traffic/``); its
+serving settings and correctness limits are in ``bench/cells/<cell>.json``.
+A model the program serves on its normal path is added by data files
+alone: its configuration (``arch``, every key an ``ArchConfig`` field,
+see ``arch_config``, and ``reference``, the plain reference module, by
+default ``bench/reference.py``; a new one is ``bench/reference_<config>.py``
+and may import helpers from ``bench/reference.py``), a cell file and a
+traffic file.  Weights and work counts follow the program's layout
+(``bench/weights.py``, ``bench/work.py``).  One run:
 
 1. set-up: random weights from the seed on the device, planted as the
    cell says (``bench/weights.py``), one ``ContinuousEngine`` in DSA kernel
@@ -28,6 +35,7 @@ import time
 T_START = time.monotonic()
 
 import argparse
+import dataclasses
 import gc
 import importlib.util
 import json
@@ -35,6 +43,7 @@ import os
 import shutil
 import sys
 import threading
+import typing
 from pathlib import Path
 from types import SimpleNamespace
 
@@ -42,31 +51,53 @@ ROOT = Path(__file__).resolve().parents[1]
 BENCH = ROOT / "bench"
 CACHE_DIR = ROOT / ".jax_cache"
 TRACE_DIR = ROOT / ".bench_trace"
+# the plain reference of a configuration that names none
+DEFAULT_REFERENCE = "bench/reference.py"
 
 
 def log(msg: str) -> None:
     print(msg, file=sys.stderr, flush=True)
 
 
-def load_cell(name: str) -> SimpleNamespace:
-    """Everything a cell is made of, found by name."""
-    spec = json.loads((ROOT / "BENCHMARK.json").read_text())
+def load_cell(name: str, root: Path = ROOT) -> SimpleNamespace:
+    """Everything a cell is made of, found by name under ``root``: its
+    entry in ``BENCHMARK.json``, its configuration's file and the
+    reference module that file names, ``bench/cells/<cell>.json`` and
+    ``bench/traffic/<traffic>.json``."""
+    spec = json.loads((root / "BENCHMARK.json").read_text())
     cells = {w["name"]: w for w in spec["workloads"]}
     if name not in cells:
         raise SystemExit(f"unknown workload {name!r}; known: {sorted(cells)}")
     w = cells[name]
+    conf = json.loads((root / {c["name"]: c["file"] for c in spec["configs"]}[
+        w["config"]]).read_text())
 
     def mine(metrics):
         return [m for m in metrics if name in m.get("workloads", [name])]
 
     from bench import generator
     return SimpleNamespace(
-        name=name, chips=w["chips"],
-        arch=json.loads((BENCH / "configs" / f"{w['config']}.json")
-                        .read_text())["arch"],
-        mix=generator.load(w["traffic"]),
-        cell=json.loads((BENCH / "cells" / f"{name}.json").read_text()),
+        name=name, chips=w["chips"], arch=conf["arch"],
+        reference=reference_module(conf.get("reference", DEFAULT_REFERENCE),
+                                   root),
+        mix=generator.load(w["traffic"], root / "bench"),
+        cell=json.loads((root / "bench" / "cells" / f"{name}.json")
+                        .read_text()),
         end_to_end=mine(spec["end_to_end"]), per_layer=mine(spec["per_layer"]))
+
+
+def reference_module(path: str, root: Path = ROOT):
+    """The plain reference a configuration names (a path under ``root``):
+    a module with ``geometry`` and ``served_logits``.  One of this
+    package's own files is imported as ``bench.<name>``."""
+    file = (root / path).resolve()
+    if file.parent == BENCH.resolve():
+        return importlib.import_module(f"bench.{file.stem}")
+    spec = importlib.util.spec_from_file_location(
+        f"bench_reference_{file.stem}", file)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def reader(metric: str):
@@ -133,17 +164,44 @@ class TraceSpan(threading.Thread):
 
 
 def arch_config(arch: dict):
-    """The program's ``ArchConfig`` for a configuration file's ``arch``."""
-    from repro.configs.base import ArchConfig, DSAConfig
+    """The program's ``ArchConfig`` for a configuration file's ``arch``.
+
+    Every key is an ``ArchConfig`` field; a nested block (``moe``, ``mla``,
+    ``mamba``, ``rwkv``, ``dsa``) becomes the dataclass its field's type
+    names, and a list a tuple.  ``family`` defaults to ``"dense"``; DSA is
+    on unless the block says otherwise.  A key the program does not know
+    stops the run, named.  The configuration's ``dsa.decode_local`` must be
+    the program's fixed decode window."""
+    from repro.configs.base import ArchConfig
     from repro.models.attention import DECODE_LOCAL
     dsa = dict(arch["dsa"])
     if dsa.pop("decode_local") != DECODE_LOCAL:
         raise SystemExit(f"the program's decode window is {DECODE_LOCAL} "
                          f"rows, the configuration states "
                          f"{arch['dsa']['decode_local']}")
-    fields = {k: v for k, v in arch.items() if k != "dsa"}
-    return ArchConfig(family="dense", dsa=DSAConfig(enabled=True, **dsa),
-                      **fields)
+    return _dataclass(ArchConfig, {"family": "dense", **arch,
+                                   "dsa": {"enabled": True, **dsa}}, "arch")
+
+
+def _dataclass(cls, values: dict, where: str):
+    hints = typing.get_type_hints(cls)
+    kw = {}
+    for key, val in values.items():
+        if key not in hints:
+            raise SystemExit(f"{where}.{key}: the program's "
+                             f"{cls.__name__} has no field {key!r}")
+        sub = [t for t in (hints[key], *typing.get_args(hints[key]))
+               if dataclasses.is_dataclass(t)]
+        if isinstance(val, dict) and sub:
+            val = _dataclass(sub[0], val, f"{where}.{key}")
+        kw[key] = _tuples(val)
+    return cls(**kw)
+
+
+def _tuples(val):
+    if isinstance(val, (list, tuple)):
+        return tuple(_tuples(v) for v in val)
+    return val
 
 
 def build_engine(cfg, weights, serving: dict):
@@ -189,20 +247,13 @@ def requests(specs):
 def run_cell(c: SimpleNamespace, seed: int, seconds: float, trace: bool,
              clog: CompileLog, devices) -> dict:
     """Set-up, window, check and metrics of one run; returns the result."""
-    import jax
-    from bench import check, generator, reference, weights, work
-    from repro.models.transformer import init_model
+    from bench import check, generator, weights, work
 
     arch, serving, plant = c.arch, c.cell["serving"], c.cell.get("plant")
+    reference = c.reference
     peak = work.peak(devices[0].device_kind)
     cfg = arch_config(arch)
-    layout = jax.eval_shape(lambda k: init_model(k, cfg)[0],
-                            jax.random.PRNGKey(0))
     w = weights.make(arch, seed, plant)
-    if jax.tree.map(lambda a: (a.shape, a.dtype), w) != jax.tree.map(
-            lambda a: (a.shape, a.dtype), layout):
-        raise SystemExit("the benchmark's weight tree does not match the "
-                         "program's layout")
     specs = generator.generate(c.mix, seconds, seed, arch["vocab"], plant,
                                arch["dsa"]["block_k"])
     max_len, max_new = serving["max_len"], c.mix["output"]["max"]
@@ -253,7 +304,8 @@ def run_cell(c: SimpleNamespace, seed: int, seconds: float, trace: bool,
 
     prompts = {s.rid: s.prompt for s in specs}
     picked = check.sample(results, c.cell["check"]["sample"], seed)
-    numbers = check.readings(w, arch, max_len, max_new, prompts, picked)
+    numbers = check.readings(w, arch, max_len, max_new, prompts, picked,
+                             ref=reference)
     limits = c.cell["check"]["limits"]
     correct = check.judge(picked, arch["vocab"], numbers, limits)
 

@@ -317,9 +317,8 @@ def main(argv=None) -> int:
 
     def warm_and_read(eng, rows):
         warm(eng, rows)
-        from bench import reference
-        bucket = reference.geometry(c.arch, c.cell["serving"]["max_len"],
-                                    c.mix["prompt"]["max"])["bucket"]
+        bucket = c.reference.geometry(c.arch, c.cell["serving"]["max_len"],
+                                      c.mix["prompt"]["max"])["bucket"]
         got["scopes"] = program_scopes(eng, bucket)
         got["stats"] = eng.stats              # the dict serve() fills
         serve = eng.serve

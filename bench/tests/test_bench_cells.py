@@ -58,7 +58,9 @@ def test_bench_cells_config_layout(c):
     cfg = run.arch_config(arch)
     program = jax.eval_shape(lambda k: init_model(k, cfg)[0],
                              jax.random.PRNGKey(0))
-    flat = {"/".join(str(getattr(p, "key", p)) for p in path): leaf.shape
+    flat = {"/".join(str(getattr(p, "key", p)) for p in path):
+            (leaf.shape, leaf.dtype)
             for path, leaf in jax.tree_util.tree_flatten_with_path(
                 program)[0]}
-    assert flat == weights.shapes(arch)
+    assert flat == {p: (leaf.shape, leaf.dtype)
+                    for p, leaf in weights.layout(arch).items()}

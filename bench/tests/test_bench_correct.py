@@ -50,6 +50,7 @@ def cell():
     spec = json.loads((ROOT / "BENCHMARK.json").read_text())
     return SimpleNamespace(
         name="stablelm_3b.chat", chips=1, arch=ARCH, mix=MIX,
+        reference=reference,
         cell={"serving": SERVING, "plant": PLANT,
               "check": {"sample": 64, "limits": LIMITS}},
         end_to_end=[m for m in spec["end_to_end"] if "stablelm_3b.chat"
@@ -137,3 +138,34 @@ def test_bench_correct_run_with_altered_tokens(fault, monkeypatch,
     assert list(out)[-1] == "compared"
     assert set(out["metrics"]) == {"ttft_p50_ms", "tpot_p90_ms",
                                    "setup_s"}
+
+
+@pytest.mark.parametrize("fault", [None, "token"])
+def test_bench_correct_batch_run_with_altered_token(fault, monkeypatch,
+                                                   tpu_peaks):
+    """A batch cell's shape of run (every request at t=0, more requests
+    than slots, output_tok_s): correct, and not once the first
+    token is altered where the engine samples it."""
+    build = run.build_engine
+
+    def broken(cfg, w, serving):
+        eng = build(cfg, w, serving)
+        sample = eng._sample_tok0
+
+        def altered(last_row, req):
+            tok0, key = sample(last_row, req)
+            return (tok0 + 1) % ARCH["vocab"], key
+        eng._sample_tok0 = altered
+        return eng
+
+    if fault == "token":
+        monkeypatch.setattr(run, "build_engine", broken)
+    c = cell()
+    c.mix = dict(MIX, arrival="batch", rate_rps=3.0)
+    c.cell = dict(c.cell, serving=dict(SERVING, slots=4))
+    c.end_to_end = [{"name": m, "unit": "-"} for m in
+                    ("output_tok_s", "tpot_p90_ms", "setup_s")]
+    out = run.run_cell(c, 12, 3.0, False, run.CompileLog(), [CpuDevice()])
+    assert out["correct"] is (fault is None)
+    assert out["attempted"] == 9 and out["failed"] == 0
+    assert set(out["metrics"]) == {"output_tok_s", "tpot_p90_ms", "setup_s"}

@@ -87,8 +87,8 @@ def test_bench_traffic_planted_weights():
     """The planted leaves of a tiny tree, as ``bench/weights.py`` states."""
     arch = dict(name="tiny", n_layers=2, d_model=64, n_heads=4,
                 n_kv_heads=2, head_dim=16, d_ff=128, vocab=512,
-                param_dtype="float32",
-                dsa=dict(sigma=0.25))
+                dtype="float32", param_dtype="float32",
+                dsa=dict(sigma=0.25, decode_local=64))
     w = weights.make(arch, 4, {"marker_ids": [1, 33]})
     emb = np.asarray(w["embed"])
     np.testing.assert_array_equal(emb[:, 0], (np.arange(512) >= 1)
@@ -118,6 +118,65 @@ def test_bench_traffic_poisson_arrivals():
     g = np.sort(np.diff(t))
     q = np.sort(generator.gaps(mix, 60))
     assert np.isin(np.round(g, 9), np.round(q, 9)).all()
+
+
+@pytest.mark.parametrize("rate,seconds,n", [(10.0, 51, 510), (0.1, 40, 4),
+                                            (2.5, 3, 8)])
+def test_bench_traffic_batch_count(rate, seconds, n):
+    """A batch mix sends round(rate_rps * seconds) requests, all at t=0."""
+    mix = dict(generator.load("chat"), arrival="batch", rate_rps=rate)
+    reqs = generator.generate(mix, seconds, 2 ** 31 + 9, 50304)
+    assert len(reqs) == n
+    assert all(r.arrival_s == 0.0 for r in reqs)
+
+
+GRADED = {"marker_ids": [1, 16],
+          "graded": {"levels": [round(2.0 * 1.12 ** g, 4) for g in range(15)],
+                     "rows": 8}}
+
+
+@pytest.mark.parametrize("plen", [16000, 1000, 200])
+def test_bench_traffic_graded_blocks(plen):
+    """Up to one graded block per level, never the first, each with its
+    rows of one marker id; no two blocks share a level; the rest, and the
+    partial last block, hold no marker."""
+    rng = np.random.default_rng(3)
+    tokens = rng.integers(16, 50304, size=plen).astype(np.int32)
+    generator.plant(tokens, rng, GRADED, 128)
+    marker = (tokens >= 1) & (tokens < 16)
+    n_full = plen // 128
+    ids = []
+    for b in range(n_full):
+        rows = tokens[b * 128:(b + 1) * 128][marker[b * 128:(b + 1) * 128]]
+        if len(rows):
+            assert b > 0 and len(rows) == 8 and len(set(rows)) == 1
+            ids.append(int(rows[0]))
+    assert len(ids) == min(15, n_full - 1) == len(set(ids))
+    assert not marker[n_full * 128:].any()
+
+
+def test_bench_traffic_graded_weights():
+    """Marker id lo + g carries channel-0 level levels[g]; every leaf whose
+    last logical axis is the residual (here attention ``wo``, the experts'
+    ``w2``) leaves channels 0 and 1 alone."""
+    arch = dict(name="tiny", family="moe", n_layers=2, d_model=64,
+                n_heads=4, n_kv_heads=2, head_dim=16, d_ff=128, vocab=512,
+                dtype="float32", param_dtype="float32",
+                moe=dict(num_experts=4, top_k=2, d_ff_expert=32),
+                dsa=dict(sigma=0.25, decode_local=64))
+    w = weights.make(arch, 5, GRADED)
+    emb = np.asarray(w["embed"])
+    levels = np.zeros(512, np.float32)
+    levels[1:16] = GRADED["graded"]["levels"]
+    np.testing.assert_array_equal(emb[:, 0], levels)
+    assert (emb[:, 1] == 1).all()
+    g = w["groups"]["b0"]
+    for leaf in (g["attn"]["wo"], g["mlp"]["w2"]):
+        assert (np.asarray(leaf)[..., :2] == 0).all()
+        assert (np.asarray(leaf)[..., 2:] != 0).any()
+    assert (np.asarray(g["mlp"]["router"])[:2] != 0).any()
+    with pytest.raises(ValueError, match="levels"):
+        weights.make(arch, 5, dict(GRADED, marker_ids=[1, 9]))
 
 
 def result(status="ok", n_new=5, arrival=0.0, admit=1.0, first=1.5,

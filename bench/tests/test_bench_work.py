@@ -21,7 +21,8 @@ def geo(plen=1):
 
 
 def test_bench_work_weights_and_token():
-    assert work._layer_params(ARCH) == LAYER
+    assert work._matmul_params(ARCH) == 2 * LAYER
+    assert work.attn_layers(ARCH) == 2
     assert work.token_flops(ARCH) == 2 * (2 * LAYER + 64 * 512)
     # layers (+ two norms each), the head, the final norm; 2 bytes each
     assert work.weight_bytes(ARCH) == 2 * (2 * (LAYER + 128) + 64 * 512 + 64)

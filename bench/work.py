@@ -1,14 +1,19 @@
 """Operations and bytes the served model needs, and the chip's peaks.
 
-Every count comes from the configuration and from the contexts the window
-served, never from how the program happens to compute them, so removing
-wasted work raises a share and a roofline share cannot pass 100% unless a
-count or a time is wrong.  DSA counts take the blocks the configuration
-keeps (``reference.geometry``): a decode step at context c reads the real
-rows of ``nb_keep_dec`` blocks, a prompt query block the real rows of
-``nb_keep_pre`` blocks at or before it.  Bytes are HBM traffic: each
-weight read once per decode step (once per prompt in prefill), each kept
-K/V row read once, each new K/V/K~ row written once.
+Every count comes from the configuration, the program's weight layout
+(``weights.layout``: which weights a token passes through) and the
+contexts the window served, never from how the program happens to
+compute them, so removing wasted work raises a share and a roofline share
+cannot pass 100% unless a count or a time is wrong.  DSA counts take the
+blocks the configuration keeps (``reference.geometry``): a decode step at
+context c reads the real rows of ``nb_keep_dec`` blocks, a prompt query
+block the real rows of ``nb_keep_pre`` blocks at or before it.  Bytes are
+HBM traffic: each weight read once per decode step (once per prompt in
+prefill), each kept K/V row read once, each new K/V/K~ row written once.
+A routed expert's weights (a leaf with an ``"expert"`` axis) count at
+``top_k`` of ``num_experts`` in FLOPs, which only the routed experts
+need, and whole in bytes, which a step reads; attention and DSA counts
+take the layers that hold a DSA predictor.
 """
 from __future__ import annotations
 
@@ -19,7 +24,7 @@ from typing import Iterable
 
 import numpy as np
 
-from bench.weights import predictor_k, shapes
+from bench.weights import layout, predictor_k
 
 HERE = Path(__file__).resolve().parent
 
@@ -55,25 +60,40 @@ def _itemsize(arch: dict) -> int:
     return int(np.dtype(arch["dtype"]).itemsize)
 
 
-def _layer_params(arch: dict) -> int:
-    """Matmul weights of one layer (attention, DSA predictor, MLP)."""
-    sh = shapes(arch)
-    return sum(int(np.prod(s[1:])) for p, s in sh.items()
-               if p.startswith("groups/") and "norm" not in p)
+def _matmul_params(arch: dict) -> int:
+    """Matmul weights one token goes through in all layers (attention, DSA
+    predictor, MLP or experts): every layer leaf but the norms and the
+    biases, a routed expert leaf at top_k of num_experts."""
+    n = 0
+    for path, leaf in layout(arch).items():
+        if (not path.startswith(("groups/", "prologue/"))
+                or "norm" in path.rsplit("/", 1)[-1] or len(leaf.core) < 2):
+            continue
+        if "expert" in leaf.axes:
+            n += leaf.size // arch["moe"]["num_experts"] * arch["moe"]["top_k"]
+        else:
+            n += leaf.size
+    return n
+
+
+def attn_layers(arch: dict) -> int:
+    """Layers that hold a DSA predictor (and so a K/V and K~ cache)."""
+    return sum(leaf.shape[0] if leaf.stacked else 1
+               for path, leaf in layout(arch).items()
+               if path.endswith("attn/dsa/p"))
 
 
 def weight_bytes(arch: dict) -> float:
-    """Weights a full forward pass reads: every layer, final norm, head."""
-    sh = shapes(arch)
-    n = (arch["n_layers"] * (_layer_params(arch) + 2 * arch["d_model"])
-         + int(np.prod(sh["lm_head"])) + arch["d_model"])
-    return float(n * np.dtype(arch["param_dtype"]).itemsize)
+    """Weights a full forward pass reads: every leaf but the embedding
+    table (a lookup reads a row), each expert whole."""
+    return float(sum(leaf.size * np.dtype(leaf.dtype).itemsize
+                     for path, leaf in layout(arch).items()
+                     if path != "embed"))
 
 
 def token_flops(arch: dict) -> float:
     """Matmul FLOPs of one token through every layer and the head."""
-    return 2.0 * (arch["n_layers"] * _layer_params(arch)
-                  + arch["d_model"] * arch["vocab"])
+    return 2.0 * (_matmul_params(arch) + arch["d_model"] * arch["vocab"])
 
 
 def decode_kv_lens(results) -> list:
@@ -101,8 +121,8 @@ def decode_kernel(arch: dict, geo: dict, kv_lens: Iterable[int]) -> Work:
     per_layer = Work(4.0 * hq * hd * rows.sum(),
                      rows.sum() * 2 * hkv * hd * b
                      + len(rows) * 2 * hq * hd * b)
-    return Work(per_layer.flops * arch["n_layers"],
-                per_layer.bytes * arch["n_layers"])
+    n = attn_layers(arch)
+    return Work(per_layer.flops * n, per_layer.bytes * n)
 
 
 def decode_steps(arch: dict, geo: dict, kv_lens: Iterable[int],
@@ -120,10 +140,10 @@ def decode_steps(arch: dict, geo: dict, kv_lens: Iterable[int],
     per_layer = Work(2.0 * k * blocks,
                      blocks * k * b + n * (2 * hkv * hd + 3 * k) * b)
     w = decode_kernel(arch, geo, kv_lens)
-    return Work(token_flops(arch) * n + w.flops
-                + per_layer.flops * arch["n_layers"],
+    layers = attn_layers(arch)
+    return Work(token_flops(arch) * n + w.flops + per_layer.flops * layers,
                 weight_bytes(arch) * steps + w.bytes
-                + per_layer.bytes * arch["n_layers"])
+                + per_layer.bytes * layers)
 
 
 def _prompt_rows(geo: dict, plen: int):
@@ -152,7 +172,8 @@ def chunk_kernel(arch: dict, geo_of, prompt_lens: Iterable[int]) -> Work:
         w = w + Work(4.0 * hq * hd * keys.sum(),
                      blocks.sum() * geo["block_k"] * 2 * hkv * hd * b
                      + plen * 2 * hq * hd * b)
-    return Work(w.flops * arch["n_layers"], w.bytes * arch["n_layers"])
+    n = attn_layers(arch)
+    return Work(w.flops * n, w.bytes * n)
 
 
 def prefill(arch: dict, geo_of, prompt_lens: Iterable[int]) -> Work:
@@ -176,8 +197,8 @@ def prefill(arch: dict, geo_of, prompt_lens: Iterable[int]) -> Work:
     # every prompt row through every layer; the head only for the last row
     n_tok = sum(prompt_lens)
     head = 2.0 * arch["d_model"] * arch["vocab"]
+    layers = attn_layers(arch)
     return Work((token_flops(arch) - head) * n_tok + head * len(prompt_lens)
-                + w.flops
-                + sel.flops * arch["n_layers"],
+                + w.flops + sel.flops * layers,
                 weight_bytes(arch) * len(prompt_lens) + w.bytes
-                + sel.bytes * arch["n_layers"])
+                + sel.bytes * layers)
